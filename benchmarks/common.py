@@ -33,11 +33,8 @@ def time_call(fn, *args, repeats: int = 3, warmup: int = 1, **kw):
 
 
 def _block(x):
-    try:
-        import jax
-        jax.block_until_ready(x)
-    except Exception:
-        pass
+    import jax
+    jax.block_until_ready(x)
 
 
 def emit(name: str, us_per_call: float, derived: str = "",

@@ -103,6 +103,8 @@ def main(argv=None):
                          "--compare (timer noise)")
     args = ap.parse_args(argv)
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from . import (common, endurance, fig09_latency_sweep, fig10_energy_sweep,
                    fig11_12_dataset_sweep, fig13_scaling, profile_bench,
                    roofline_table, sdtw_kernel_bench, search_bench,

@@ -28,11 +28,9 @@ from typing import Optional
 import jax
 import numpy as np
 
-from repro.compat import tree_flatten_with_path
-
 
 def _flatten_with_paths(tree):
-    flat, treedef = tree_flatten_with_path(tree)
+    flat, treedef = jax.tree.flatten_with_path(tree)
     paths = ["/".join(str(getattr(k, "key", getattr(k, "idx", k)))
                       for k in path) for path, _ in flat]
     leaves = [leaf for _, leaf in flat]

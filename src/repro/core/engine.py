@@ -354,11 +354,11 @@ def _execute_sdtw(req: SdtwRequest):
                          return_positions=return_positions,
                          return_spans=return_spans)
     elif impl == "pallas":
-        from repro.kernels.sdtw import resolve_blocks, sdtw_pallas
+        from repro.kernels.sdtw import (interpret_mode, resolve_blocks,
+                                        sdtw_pallas)
         if explain:
-            interp = jax.default_backend() != "tpu"
             rbq, rbm, rscheme, rrt = resolve_blocks(
-                nq, m, block_q, block_m, None, None, interp, n=n,
+                nq, m, block_q, block_m, None, None, interpret_mode(), n=n,
                 metric=metric, dtype=dtype, tune=tune, span=return_spans)
             config = {"block_q": rbq, "block_m": rbm,
                       "scan_scheme": rscheme, "row_tile": rrt}

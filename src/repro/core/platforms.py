@@ -67,9 +67,10 @@ class BackendModel:
     ``interpret`` constants were fitted to in-container XLA-CPU
     measurements of the committed bench shapes (see
     ``repro/tune/tables/interpret.json`` provenance); the ``tpu``
-    constants are anchored to the v5e roofline (``launch/roofline.V5E``)
-    and the kernel's documented VMEM working set — on real TPU hardware
-    the measured stage (``tune='measure'``) refines them into the table.
+    constants (``TPU_BACKENDS``, keyed by device kind) are anchored to the
+    chip's published roofline (``launch/roofline.PEAKS``) and the kernel's
+    documented VMEM working set — on real TPU hardware the measured stage
+    (``tune='measure'``) refines them into the table.
     """
     name: str                    # 'interpret' (XLA CPU) | 'tpu'
     call_fixed_us: float         # per-dispatch overhead of one jitted call
@@ -109,11 +110,11 @@ INTERPRET_BACKEND = BackendModel(
     hbm_bw_bytes_per_s=20e9, vmem_budget_words=1 << 21)
 
 #: TPU v5e — roofline-anchored (819 GB/s HBM, ~16 MB VMEM/core): the
-#: vector unit makes the Hillis-Steele 'shift' scan the cheap scheme, the
-#: per-cell cost is far below CPU, and the binding constraint is the VMEM
-#: working set ``block_q * (3*block_m + 3*N)`` words (span mode
-#: ``block_q * (6*block_m + 5*N)``).
-TPU_BACKEND = BackendModel(
+#: vector unit runs the Hillis-Steele 'shift' scan (the only scheme Mosaic
+#: lowers), the per-cell cost is far below CPU, and the binding constraint
+#: is the VMEM working set ``block_q * (3*block_m + 3*N)`` words (span mode
+#: ``block_q * (6*block_m + 5*N)``). Never calibrated against a chip run.
+TPU_V5E_BACKEND = BackendModel(
     name="tpu", call_fixed_us=30.0, row_step_fixed_us=2.0,
     scan_elem_us=0.0004, wf_step_fixed_us=1.0, wf_elem_us=0.001,
     chunk_fixed_us=40.0, cache_elems=1 << 21, tile_fixed_us=3.5,
@@ -122,14 +123,36 @@ TPU_BACKEND = BackendModel(
     scheme_mult=(("shift", 1.0), ("assoc", 1.4)),
     hbm_bw_bytes_per_s=819e9, vmem_budget_words=1 << 21)
 
-BACKENDS = {b.name: b for b in (INTERPRET_BACKEND, TPU_BACKEND)}
+#: TPU cost constants keyed by the ``device_kind`` the chip reports
+#: (``jax.devices()[0].device_kind``; a v5e reports "TPU v5 lite"). A chip
+#: missing here is an error, never priced as some other chip.
+TPU_BACKENDS = {"TPU v5 lite": TPU_V5E_BACKEND}
+
+
+def tpu_device_kind() -> str:
+    """The ``device_kind`` of the attached TPU; an error off-TPU."""
+    import jax
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        raise RuntimeError(f"no TPU attached (default device is "
+                           f"{dev.platform!r}); TPU cost constants need one")
+    return dev.device_kind
 
 
 def backend_model(name: str) -> BackendModel:
-    """The cost-constant set for an execution backend; every non-TPU
-    backend string ('cpu', 'gpu', ...) maps to the interpret model until
-    it gets its own calibration."""
-    return BACKENDS.get(name, INTERPRET_BACKEND)
+    """The cost-constant set for a tuning backend: 'interpret' (the CPU,
+    where the kernel runs in interpret mode) or 'tpu' (looked up by the
+    attached chip's ``device_kind``). Anything else is an error."""
+    if name == "interpret":
+        return INTERPRET_BACKEND
+    if name != "tpu":
+        raise ValueError(f"no cost constants for backend {name!r}; "
+                         "expected 'interpret' or 'tpu'")
+    kind = tpu_device_kind()
+    if kind not in TPU_BACKENDS:
+        raise ValueError(f"no TPU cost constants for device_kind {kind!r}; "
+                         f"known kinds: {sorted(TPU_BACKENDS)}")
+    return TPU_BACKENDS[kind]
 
 
 CPU_ARM = PlatformModel(

@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.distributed.collectives import neighbor_perm, psum_harvest
 
 
@@ -74,7 +73,7 @@ def pipeline_apply(block_fn, stage_params, x_micro, mesh, axis: str = "stage"):
         return psum_harvest(outs, axis, n_stages, n_micro)
 
     in_specs = jax.tree.map(lambda p: P(axis), stage_params)
-    return shard_map(
+    return jax.shard_map(
         stage_body, mesh=mesh,
         in_specs=(in_specs, P()),
         out_specs=P(),

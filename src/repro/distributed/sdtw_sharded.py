@@ -44,7 +44,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.distances import accum_dtype
 from repro.core.sdtw import (default_excl_zone, sdtw_carry_init,
                              sdtw_segment, sdtw_segment_topk)
@@ -279,7 +278,7 @@ def build_pipeline(mesh: Mesh, *, dp_axis: Optional[str], mp_axis: str,
     in_specs = (P(None, mp_axis), mspec, mspec, mspec, mspec, P(), P())
     if with_carry:
         in_specs = in_specs + (mspec,)             # pytree prefix
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=in_specs,
         out_specs=mspec,                           # pytree prefix

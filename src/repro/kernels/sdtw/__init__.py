@@ -1,3 +1,4 @@
-from .ops import pallas_carry_init, resolve_blocks, sdtw_pallas
+from .ops import interpret_mode, pallas_carry_init, resolve_blocks, sdtw_pallas
 
-__all__ = ["pallas_carry_init", "resolve_blocks", "sdtw_pallas"]
+__all__ = ["interpret_mode", "pallas_carry_init", "resolve_blocks",
+           "sdtw_pallas"]

@@ -1,9 +1,9 @@
 """Jitted wrapper around the sDTW Pallas kernel.
 
 Handles padding/alignment, BlockSpec plumbing, dtype promotion, the
-interpret-mode fallback (this container is CPU-only; TPU is the target —
-``interpret=None`` auto-selects interpret mode off-TPU, per the validation
-protocol), and the chunk-carry protocol: a call may start from a
+execution mode (``interpret=None`` asks ``interpret_mode``: Mosaic-compiled
+on the TPU, Pallas interpret mode on the CPU where the tests run, refused
+on any other backend), and the chunk-carry protocol: a call may start from a
 (boundary-column, best) carry produced by a previous call over an earlier
 reference slice and return the carry for the next slice, so an arbitrarily
 long reference can be streamed through fixed-shape kernel launches — the
@@ -16,7 +16,7 @@ Auto-tuning (``block_q``/``block_m``/``scan_scheme``/``row_tile`` default
 to ``None``): with ``tune='off'`` (the kernel-level default) the legacy
 hand-tuned constants apply — on TPU the sublane-aligned (8, 512) block
 with the Hillis-Steele ``"shift"`` scan and ``row_tile=8``; in interpret
-mode (off-TPU) the block is fitted to the actual batch (no sublane
+mode (CPU) the block is fitted to the actual batch (no sublane
 constraint to respect) with a tile large enough to cover the reference up
 to a working-set budget, the work-efficient ``"assoc"`` scan, and no row
 unrolling (XLA-CPU gains nothing from it). With ``tune='model'`` (what
@@ -40,11 +40,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.distances import INT_FAR, accum_dtype, big
-from .sdtw import _sdtw_kernel
+from .sdtw import LANES, _sdtw_kernel
 
 DEFAULT_BLOCK_Q = 8     # sublane-aligned query block (TPU)
 DEFAULT_BLOCK_M = 512   # lane-aligned reference tile (multiple of 128, TPU)
-DEFAULT_ROW_TILE = 8    # rows per boundary-column slice access (TPU)
+DEFAULT_ROW_TILE = 8    # DP rows unrolled per loop iteration (TPU)
 
 #: Interpret-mode working-set budget: block_q * block_m is kept at or
 #: under this many accumulator elements (~8 MB int32 per live row array).
@@ -58,6 +58,22 @@ def _ceil_to(x: int, m: int) -> int:
 
 def _pow2_at_least(x: int) -> int:
     return 1 << max(0, int(x) - 1).bit_length()
+
+
+def interpret_mode(backend: str | None = None) -> bool:
+    """Whether the kernel runs in Pallas interpret mode on ``backend``
+    (default: JAX's default backend). The one place this is decided:
+    the CPU interprets the kernel, the TPU compiles it with Mosaic, and
+    any other backend has no lowering and is refused — never silently
+    interpreted on an accelerator."""
+    backend = jax.default_backend() if backend is None else backend
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(
+        f"the sDTW Pallas kernel has no lowering for backend {backend!r}: "
+        "it compiles for 'tpu' and is interpreted on 'cpu' only")
 
 
 def resolve_blocks(b: int, m: int, block_q, block_m, scan_scheme, row_tile,
@@ -158,9 +174,10 @@ def sdtw_pallas(queries, reference, qlens=None, metric: str = "abs_diff",
     VMEM working set per grid cell ≈
     ``block_q · (3·block_m + 3·N)`` accumulator words plain,
     ``block_q · (6·block_m + 5·N)`` in span mode (the start lanes are
-    int32): the boundary column and (span mode) its start lane live in
-    persistent VMEM *scratch* accessed one ``row_tile``-wide slice per
-    loop iteration, and the row loop keeps ~3 (plain) / ~6 (span)
+    int32; N is padded to a multiple of 128 lanes): the boundary column
+    and (span mode) its start lane live in persistent VMEM *scratch*,
+    each DP row reading and writing the aligned 128-lane window that
+    holds its entry, and the row loop keeps ~3 (plain) / ~6 (span)
     block-wide row vectors live (prev / captured-last-row / scan
     temporaries, plus the start lanes). ``return_lastrow`` adds one
     ``block_q · block_m`` output block (+ its int32 start lane in span
@@ -207,7 +224,7 @@ def sdtw_pallas(queries, reference, qlens=None, metric: str = "abs_diff",
     Return order: ``res[, new_carry][, lastrow[, lastrow_starts]]``.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     b, n = queries.shape
     m = reference.shape[0]
     acc = accum_dtype(jnp.result_type(queries, reference))
@@ -217,6 +234,11 @@ def sdtw_pallas(queries, reference, qlens=None, metric: str = "abs_diff",
         n=n, metric=metric,
         dtype=str(jnp.result_type(queries, reference)), tune=tune,
         span=return_spans or track_start)
+    if scan_scheme == "assoc" and not interpret:
+        raise ValueError(
+            "scan_scheme='assoc' does not compile for the TPU (Mosaic "
+            "cannot lower lax.associative_scan inside the kernel); use "
+            "'shift' there — 'assoc' is an interpret-mode scheme")
 
     carry = tuple(carry) if carry is not None else ()
     track = return_spans or track_start or len(carry) == 5
@@ -249,14 +271,17 @@ def sdtw_pallas(queries, reference, qlens=None, metric: str = "abs_diff",
         qlens = jnp.full((b,), n, jnp.int32)
     bp = _ceil_to(b, block_q)
     mp = _ceil_to(max(m, block_m), block_m)
+    # Queries and boundary column are lane-padded so the kernel can read
+    # them as aligned LANES-wide windows (the padding is never a DP row).
+    npad = _ceil_to(n, LANES)
 
-    q_pad = jnp.zeros((bp, n), queries.dtype).at[:b].set(queries)
+    q_pad = jnp.zeros((bp, npad), queries.dtype).at[:b, :n].set(queries)
     r_pad = jnp.zeros((1, mp), reference.dtype).at[0, :m].set(reference)
     qlen_pad = jnp.ones((bp, 1), jnp.int32).at[:b, 0].set(qlens)
     rlen = jnp.full((1, 1), m if ref_len is None else ref_len, jnp.int32)
     lead = jnp.full((1, 1), ref_lead, jnp.int32)
     off = jnp.full((1, 1), ref_offset, jnp.int32)
-    bcol_pad = jnp.full((bp, n), BIG, acc).at[:b].set(bcol)
+    bcol_pad = jnp.full((bp, npad), BIG, acc).at[:b, :n].set(bcol)
     best_pad = jnp.full((bp, 1), BIG, acc).at[:b, 0].set(best)
     pos_pad = jnp.full((bp, 1), -1, jnp.int32).at[:b, 0].set(pos)
 
@@ -264,7 +289,7 @@ def sdtw_pallas(queries, reference, qlens=None, metric: str = "abs_diff",
     kernel = functools.partial(_sdtw_kernel, metric, n, block_m, track,
                                return_lastrow, scan_scheme, row_tile)
 
-    col_spec = pl.BlockSpec((block_q, n), lambda qb, t: (qb, 0))
+    col_spec = pl.BlockSpec((block_q, npad), lambda qb, t: (qb, 0))
     scalar_spec = pl.BlockSpec((block_q, 1), lambda qb, t: (qb, 0))
     tile_spec = pl.BlockSpec((1, block_m), lambda qb, t: (0, t))
     one_spec = pl.BlockSpec((1, 1), lambda qb, t: (0, 0))
@@ -274,8 +299,8 @@ def sdtw_pallas(queries, reference, qlens=None, metric: str = "abs_diff",
     in_specs = [col_spec, tile_spec, scalar_spec, one_spec, one_spec,
                 one_spec, col_spec]
     if track:
-        bstart_pad = jnp.full((bp, n), INT_FAR,
-                              jnp.int32).at[:b].set(bstart)
+        bstart_pad = jnp.full((bp, npad), INT_FAR,
+                              jnp.int32).at[:b, :n].set(bstart)
         inputs += [bstart_pad]
         in_specs += [col_spec]
     inputs += [best_pad, pos_pad]
@@ -287,10 +312,10 @@ def sdtw_pallas(queries, reference, qlens=None, metric: str = "abs_diff",
 
     out_specs = [scalar_spec, col_spec]
     out_shape = [jax.ShapeDtypeStruct((bp, 1), acc),
-                 jax.ShapeDtypeStruct((bp, n), acc)]
+                 jax.ShapeDtypeStruct((bp, npad), acc)]
     if track:
         out_specs += [col_spec]
-        out_shape += [jax.ShapeDtypeStruct((bp, n), jnp.int32)]
+        out_shape += [jax.ShapeDtypeStruct((bp, npad), jnp.int32)]
     out_specs += [scalar_spec]
     out_shape += [jax.ShapeDtypeStruct((bp, 1), jnp.int32)]
     if track:
@@ -303,9 +328,9 @@ def sdtw_pallas(queries, reference, qlens=None, metric: str = "abs_diff",
             out_specs += [row_spec]
             out_shape += [jax.ShapeDtypeStruct((bp, mp), jnp.int32)]
 
-    scratch_shapes = [pltpu.VMEM((block_q, n), acc)]
+    scratch_shapes = [pltpu.VMEM((block_q, npad), acc)]
     if track:
-        scratch_shapes += [pltpu.VMEM((block_q, n), jnp.int32)]
+        scratch_shapes += [pltpu.VMEM((block_q, npad), jnp.int32)]
 
     outs = pl.pallas_call(
         kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
@@ -332,10 +357,10 @@ def sdtw_pallas(queries, reference, qlens=None, metric: str = "abs_diff",
     extras = []
     if return_carry:
         if track:
-            extras.append((bound[:b], bound_start[:b], dist, end_pos,
-                           start_out[:b, 0]))
+            extras.append((bound[:b, :n], bound_start[:b, :n], dist,
+                           end_pos, start_out[:b, 0]))
         else:
-            extras.append((bound[:b], dist, end_pos))
+            extras.append((bound[:b, :n], dist, end_pos))
     if return_lastrow:
         extras.append(lastrow[:b, :m])
         if track:

@@ -19,31 +19,36 @@ Grid: (num_query_blocks, num_ref_tiles); the tile dimension is innermost and
 sequential. The DP boundary column lives in a persistent VMEM *scratch*
 buffer (``scratch_shapes`` — allocated once for the whole grid, so it
 carries across the sequential tile dimension exactly like MATSA's
-inter-subarray pass gates, §III-B) and is read/written **one row slice at
-a time** (``ref[:, pl.ds(i, …)]``): the old scheme re-read and re-wrote
-the full (block_q, N) column per DP row, i.e. O(N²·block_q) VMEM traffic
-per tile — the slice protocol makes it O(N·block_q). The final tile copies
-the scratch into the ``bound`` output so the cross-call chunk-carry
-protocol is unchanged.
+inter-subarray pass gates, §III-B). The final tile copies the scratch into
+the ``bound`` output so the cross-call chunk-carry protocol is unchanged.
+
+Lane access (what Mosaic accepts): the query block and the boundary
+scratch are padded to a multiple of 128 lanes by the wrapper, and the DP
+rows are walked one aligned 128-lane window at a time
+(``pl.ds(pl.multiple_of(w * 128, 128), 128)``): a window of queries and of
+the boundary scratch is read once, each of its rows picks its query value
+and boundary entry out of it with a one-hot lane mask and merges its new
+boundary entry back with the same mask, and the window is written back
+once. Mosaic lowers neither ``dynamic_slice`` nor a lane slice it cannot
+prove 128-aligned, and per-row scratch traffic would cost more than the
+in-register picks.
 
 Scan schemes (both exactly associative over the tropical semiring, so
 int32 results are bitwise-identical between them; float32 differs only in
 summation order):
 
   * ``"shift"`` — Hillis-Steele doubling in log2(block_m) lane-shift
-    steps: the right scheme on TPU hardware, where lane shifts are cheap
-    and the log factor is hidden by the VPU.
+    steps: the scheme Mosaic compiles for the TPU.
   * ``"assoc"`` — ``lax.associative_scan`` (work-efficient odd-even
-    recursion, O(block_m) combines): the right scheme off-TPU / in
-    interpret mode, where each shift step costs a full memory sweep and
-    the work-efficient form is ~2× faster end to end.
+    recursion, O(block_m) combines): interpret mode only, where each
+    shift step costs a full memory sweep and the work-efficient form is
+    ~2× faster end to end. Mosaic cannot lower it (its recursion slices
+    zero-width vectors); ``sdtw_pallas`` refuses it on the TPU.
 
-Row tiling: ``row_tile`` consecutive DP rows are processed per loop
-iteration — the boundary-column slice read/write is batched to one
-(block_q, row_tile) access per iteration and the loop-control overhead of
-the row loop (plus the per-row scan set-up) is amortized over the tile.
-The per-row scans themselves stay sequential (row r+1 consumes row r's
-output — the DP's true dependency).
+Row tiling: ``row_tile`` consecutive DP rows of a window are unrolled per
+loop iteration, amortizing the row loop's control overhead (and the
+per-row scan set-up) over the tile. The per-row scans themselves stay sequential
+(row r+1 consumes row r's output — the DP's true dependency).
 
 In-kernel last-row capture (``want_lastrow``): the kernel additionally
 emits row ``qlen - 1`` of the DP — the cost of a match *ending* at every
@@ -62,8 +67,8 @@ rides the scan, the boundary column, and the cross-call chunk carry. The
 plain variant keeps the untaxed value+position lanes.
 
 Accumulates in float32 or saturating int32 (see core.distances).
-Per-query exclusion zones are not supported here (ops.py falls back to the
-rowscan path); the traced ``lead``/``rlen`` window masks a *leading* /
+Per-query exclusion zones are not supported here (the engine's dispatch
+sends such calls to the rowscan path); the traced ``lead``/``rlen`` window masks a *leading* /
 *trailing* band of columns, which is what the pruned search's halo groups
 and right-padded streaming tails need.
 """
@@ -74,6 +79,26 @@ from jax import lax
 from jax.experimental import pallas as pl
 from repro.core.distances import (INT_FAR, big, lex_min, sat_add,
                                   tropical_combine, tropical_combine_span)
+
+
+#: Lane width of a TPU vector register: the query block and the boundary
+#: scratch are read and written in aligned windows of this many lanes.
+LANES = 128
+
+
+def _pick(x, hit):
+    """The lane of ``x`` selected by the one-hot ``hit``, as a (rows, 1)
+    column. Exact: every other lane contributes zero."""
+    return jnp.sum(jnp.where(hit, x, jnp.zeros_like(x)), axis=1,
+                   keepdims=True)
+
+
+def _shift_lanes(x, k, fill):
+    """``x`` moved ``k`` lanes right along axis 1; the ``k`` lanes it
+    leaves at the left take ``fill`` (a scalar or a (rows, 1) column)."""
+    sh = jnp.pad(x, ((0, 0), (k, 0)))[:, :x.shape[1]]
+    return jnp.where(lax.broadcasted_iota(jnp.int32, x.shape, 1) < k, fill,
+                     sh)
 
 
 def _distance(q, r, metric):
@@ -95,14 +120,12 @@ def _tropical_row_scan(a, u, su, big_val):
     bm = a.shape[-1]
     shift = 1
     while shift < bm:
-        a_sh = jnp.pad(a, ((0, 0), (shift, 0)), constant_values=0)[:, :bm]
-        u_sh = jnp.pad(u, ((0, 0), (shift, 0)),
-                       constant_values=big_val)[:, :bm]
+        a_sh = _shift_lanes(a, shift, 0)
+        u_sh = _shift_lanes(u, shift, big_val)
         if su is None:
             u = jnp.minimum(u, sat_add(a, u_sh))
         else:
-            su_sh = jnp.pad(su, ((0, 0), (shift, 0)),
-                            constant_values=INT_FAR)[:, :bm]
+            su_sh = _shift_lanes(su, shift, INT_FAR)
             u, su = lex_min(u, su, sat_add(a, u_sh), su_sh)
         a = sat_add(a, a_sh)
         shift *= 2
@@ -134,7 +157,8 @@ def _sdtw_kernel(metric, n, block_m, track, want_lastrow, scheme, row_tile,
     marked ⊕ exist only in the span variant; the ``lastrow`` outputs only
     with ``want_lastrow``):
 
-    q_ref:       (block_q, N)   queries (VMEM) — read once per tile
+    q_ref:       (block_q, Np)  queries (VMEM), N padded to Np, a multiple
+                                of ``LANES`` — read one window at a time
     r_ref:       (1, block_m)   reference tile (VMEM)
     qlen_ref:    (block_q, 1)   true query lengths
     rlen_ref:    (1, 1)         true reference length (columns >= rlen are
@@ -145,9 +169,9 @@ def _sdtw_kernel(metric, n, block_m, track, want_lastrow, scheme, row_tile,
     off_ref:     (1, 1)         global column offset of this reference slice
                                 (chunk-carry streaming) — reported match
                                 positions are ``off + local column``
-    bcol_in_ref: (block_q, N)   carry in: boundary column entering this call
+    bcol_in_ref: (block_q, Np)  carry in: boundary column entering this call
                                 (BIG for a fresh start)
-    bstart_in_ref:(block_q, N) ⊕ carry in: start lane of that boundary
+    bstart_in_ref:(block_q, Np)⊕ carry in: start lane of that boundary
                                 column (INT_FAR for a fresh start)
     best_in_ref: (block_q, 1)   carry in: running per-query best
     pos_in_ref:  (block_q, 1)   carry in: end position of that best (-1 for
@@ -155,10 +179,10 @@ def _sdtw_kernel(metric, n, block_m, track, want_lastrow, scheme, row_tile,
     start_in_ref:(block_q, 1) ⊕ carry in: start position of that best (-1)
     out_ref:     (block_q, 1)   running per-query best (min over last valid
                                 row)
-    bound_ref:   (block_q, N)   output: boundary column for the next slice
+    bound_ref:   (block_q, Np)  output: boundary column for the next slice
                                 (written from the VMEM scratch on the final
                                 tile — the chunk-carry protocol)
-    bound_start_ref:(block_q,N)⊕ output: start lane of the boundary column
+    bound_start_ref:(bq, Np)  ⊕ output: start lane of the boundary column
     pos_ref:     (block_q, 1)   output: global end position of the best
                                 match (leftmost column attaining it);
                                 updated only on strict improvement so
@@ -169,9 +193,9 @@ def _sdtw_kernel(metric, n, block_m, track, want_lastrow, scheme, row_tile,
                                 the DP (BIG at masked columns) — the
                                 candidate row for top-K folding
     lastrow_start_ref: ⊕        its start-pointer lane
-    bscratch:    (block_q, N)   VMEM scratch: the live boundary column,
+    bscratch:    (block_q, Np)  VMEM scratch: the live boundary column,
                                 persistent across the sequential tile grid
-    bsscratch:   (block_q, N) ⊕ VMEM scratch: its start lane
+    bsscratch:   (block_q, Np)⊕ VMEM scratch: its start lane
     """
     it = iter(refs)
     q_ref = next(it)
@@ -203,9 +227,7 @@ def _sdtw_kernel(metric, n, block_m, track, want_lastrow, scheme, row_tile,
     INT_FAR_ = jnp.int32(INT_FAR)
     scan = _SCAN_SCHEMES[scheme]
 
-    # Loop invariants, read/computed once per tile (the old kernel re-read
-    # the full q_ref inside every DP row).
-    q = q_ref[...].astype(acc)                       # (bq, N)
+    # Loop invariants, read/computed once per tile.
     r = r_ref[...].astype(acc)                       # (1, bm)
     qlen = qlen_ref[...].astype(jnp.int32)           # (bq, 1)
     rlen = rlen_ref[0, 0]
@@ -214,8 +236,11 @@ def _sdtw_kernel(metric, n, block_m, track, want_lastrow, scheme, row_tile,
     j_local = t * block_m + lax.broadcasted_iota(jnp.int32, (1, block_m), 1)
     col_ok = (j_local >= lead) & (j_local < rlen)    # (1, bm)
     gcol = jnp.broadcast_to(off + j_local, (bq, block_m))
-    lane0 = lax.broadcasted_iota(jnp.int32, (bq, block_m), 1) == 0
-    last_local = jnp.clip(rlen - 1 - t * block_m, 0, block_m - 1)
+    lane = lax.broadcasted_iota(jnp.int32, (bq, block_m), 1)
+    # This tile's last *valid* column is the next boundary (the returned
+    # carry must be S[:, rlen-1], not a BIG padding lane, for cross-call
+    # chaining to be exact); a tile past rlen keeps the old boundary.
+    at_exit = lane == jnp.clip(rlen - 1 - t * block_m, 0, block_m - 1)
     in_tile = t * block_m < rlen
 
     @pl.when(t == 0)
@@ -227,35 +252,37 @@ def _sdtw_kernel(metric, n, block_m, track, want_lastrow, scheme, row_tile,
             start_ref[...] = start_in_ref[...]
             bsscratch[...] = bstart_in_ref[...]
 
-    def one_row(i, prev, pstart, b_im1, bs_im1, b_row, bs_row, lrow, lstart):
-        """One DP row. ``b_row``/``bs_row`` are the boundary column's row-i
-        entries from the *previous* tile (read before overwrite);
-        ``b_im1``/``bs_im1`` are row i-1's. Returns the new row state plus
-        this row's boundary exit values."""
-        qi = lax.dynamic_slice_in_dim(q, i, 1, axis=1)       # (bq, 1)
+    def one_row(i, hit, qwin, bwin, bswin, prev, pstart, b_im1, bs_im1,
+                lrow, lstart):
+        """DP row ``i``; ``hit`` is its one-hot lane in the current window.
+        ``bwin``/``bswin`` hold the window's boundary entries: lane ``i``
+        and above still from the previous tile, the lanes below already
+        overwritten with this tile's exit. ``b_im1``/``bs_im1`` are row
+        i-1's previous-tile entries. Returns the updated state, with row
+        i's previous-tile entries as the next row's ``b_im1``/``bs_im1``
+        (``None`` for every start lane in the plain variant)."""
+        qi = _pick(qwin, hit)                                # (bq, 1)
         d = _distance(qi, r, metric)                         # (bq, bm)
         d = jnp.where(col_ok, d, BIG)
+        b_row = _pick(bwin, hit)
+        bs_row = _pick(bswin, hit) if track else None
 
         # prev shifted right by one lane; lane 0 takes the diagonal boundary.
-        prev_sh = jnp.pad(prev, ((0, 0), (1, 0)),
-                          constant_values=0)[:, :block_m]
-        prev_sh = jnp.where(lane0, b_im1, prev_sh)
+        prev_sh = _shift_lanes(prev, 1, b_im1)
         if track:
-            pstart_sh = jnp.pad(pstart, ((0, 0), (1, 0)),
-                                constant_values=INT_FAR)[:, :block_m]
-            pstart_sh = jnp.where(lane0, bs_im1, pstart_sh)
+            pstart_sh = _shift_lanes(pstart, 1, bs_im1)
             mn, mns = lex_min(prev_sh, pstart_sh, prev, pstart)
         else:
             mn, mns = jnp.minimum(prev_sh, prev), None
 
         u = sat_add(d, mn)
         a_p, u_p, su_p = scan(d, u, mns, BIG)
+        sstart = None
         if track:
             s_rec, ss_rec = lex_min(u_p, su_p, sat_add(a_p, b_row), bs_row)
             sstart = jnp.where(i == 0, gcol, ss_rec)
         else:
             s_rec = jnp.minimum(u_p, sat_add(a_p, b_row))
-            sstart = pstart                                  # unused dummy
         s = jnp.where(i == 0, d, s_rec)                      # free-start row
         s = jnp.where(col_ok, s, BIG)
         if track:
@@ -267,75 +294,57 @@ def _sdtw_kernel(metric, n, block_m, track, want_lastrow, scheme, row_tile,
         if track:
             lstart = jnp.where(at_last, sstart, lstart)
 
-        # This tile's last *valid* column is the next boundary (the
-        # returned carry must be S[:, rlen-1], not a BIG padding lane, for
-        # cross-call chaining to be exact); a tile past rlen keeps b_row.
-        new_b = jnp.where(
-            in_tile, lax.dynamic_slice_in_dim(s, last_local, 1, axis=1),
-            b_row)
-        new_bs = bs_row
+        bwin = jnp.where(hit, jnp.where(in_tile, _pick(s, at_exit), b_row),
+                         bwin)
         if track:
-            new_bs = jnp.where(
-                in_tile,
-                lax.dynamic_slice_in_dim(sstart, last_local, 1, axis=1),
-                bs_row)
-        return s, sstart, lrow, lstart, new_b, new_bs
+            bswin = jnp.where(
+                hit, jnp.where(in_tile, _pick(sstart, at_exit), bs_row),
+                bswin)
+        return bwin, bswin, s, sstart, b_row, bs_row, lrow, lstart
 
-    def row_block(i0, prev, pstart, b_im1, bs_im1, lrow, lstart, width):
-        """``width`` consecutive rows with one batched boundary-column
-        slice read/write (``width`` is static — either ``row_tile`` or the
-        tail remainder)."""
-        bslab = bscratch[:, pl.ds(i0, width)]                # (bq, width)
-        bsslab = bsscratch[:, pl.ds(i0, width)] if track else None
-        new_cols, new_scols = [], []
-        for rr in range(width):
-            b_row = bslab[:, rr:rr + 1]
-            bs_row = bsslab[:, rr:rr + 1] if track else None
-            prev, pstart, lrow, lstart, nb, nbs = one_row(
-                i0 + rr, prev, pstart, b_im1, bs_im1, b_row, bs_row,
-                lrow, lstart)
-            b_im1, bs_im1 = b_row, bs_row
-            new_cols.append(nb)
-            new_scols.append(nbs)
-        bscratch[:, pl.ds(i0, width)] = jnp.concatenate(new_cols, axis=1)
+    def window(w0, rows, state):
+        """DP rows ``w0 .. w0 + rows - 1``: the aligned ``LANES``-wide
+        window of the queries and of the boundary scratch holding them is
+        read once and written back once. ``row_tile`` rows are unrolled
+        per loop iteration (a static remainder closes the window)."""
+        wl = pl.ds(w0, LANES)
+        qwin = q_ref[:, wl].astype(acc)
+        wlane = lax.broadcasted_iota(jnp.int32, (bq, LANES), 1)
+
+        def rows_from(r0, width, st):
+            for rr in range(width):
+                st = one_row(w0 + r0 + rr, wlane == r0 + rr, qwin, *st)
+            return st
+
+        n_main, n_tail = divmod(rows, row_tile)
+        st = (bscratch[:, wl], bsscratch[:, wl] if track else None) + state
+        st = lax.fori_loop(
+            0, n_main, lambda ib, st: rows_from(ib * row_tile, row_tile, st),
+            st)
+        if n_tail:
+            st = rows_from(n_main * row_tile, n_tail, st)
+        bscratch[:, wl] = st[0]
         if track:
-            bsscratch[:, pl.ds(i0, width)] = jnp.concatenate(new_scols,
-                                                             axis=1)
-        return prev, pstart, b_im1, bs_im1, lrow, lstart
+            bsscratch[:, wl] = st[1]
+        return st[2:]
 
-    prev0 = jnp.full((bq, block_m), BIG, acc)
-    pstart0 = jnp.full((bq, block_m), INT_FAR_, jnp.int32)
-    b0 = jnp.full((bq, 1), BIG, acc)
-    bs0 = jnp.full((bq, 1), INT_FAR_, jnp.int32)
-    lrow0 = jnp.full((bq, block_m), BIG, acc)
-    lstart0 = jnp.full((bq, block_m), INT_FAR_, jnp.int32)
-
-    n_main, n_tail = divmod(n, row_tile)
-    if track:
-        def body(ib, carry):
-            return row_block(ib * row_tile, *carry, row_tile)
-
-        carry = (prev0, pstart0, b0, bs0, lrow0, lstart0)
-        carry = lax.fori_loop(0, n_main, body, carry)
-        if n_tail:
-            carry = row_block(n_main * row_tile, *carry, n_tail)
-        _, _, _, _, lrow, lstart = carry
-    else:
-        # Keep the loop carry lean in the plain variant (no start lanes).
-        def body(ib, carry):
-            prev, b_im1, lrow = carry
-            prev, _, b_im1, _, lrow, _ = row_block(
-                ib * row_tile, prev, pstart0, b_im1, bs0, lrow, lstart0,
-                row_tile)
-            return prev, b_im1, lrow
-
-        prev, b_im1, lrow = lax.fori_loop(0, n_main, body,
-                                          (prev0, b0, lrow0))
-        if n_tail:
-            _, _, _, _, lrow, _ = row_block(
-                n_main * row_tile, prev, pstart0, b_im1, bs0, lrow, lstart0,
-                n_tail)
-        lstart = lstart0
+    # Row state: (prev, pstart, b_im1, bs_im1, lrow, lstart); the start
+    # lanes are None in the plain variant, so its loop carry stays lean.
+    state = (jnp.full((bq, block_m), BIG, acc),
+             jnp.full((bq, block_m), INT_FAR_, jnp.int32) if track else None,
+             jnp.full((bq, 1), BIG, acc),
+             jnp.full((bq, 1), INT_FAR_, jnp.int32) if track else None,
+             jnp.full((bq, block_m), BIG, acc),
+             jnp.full((bq, block_m), INT_FAR_, jnp.int32) if track else None)
+    n_full, n_last = divmod(n, LANES)
+    if n_full:
+        state = lax.fori_loop(
+            0, n_full,
+            lambda w, st: window(pl.multiple_of(w * LANES, LANES), LANES, st),
+            state)
+    if n_last:
+        state = window(n_full * LANES, n_last, state)
+    lrow, lstart = state[4], state[5]
 
     # Harvest best / end position / start from the captured last row, once
     # per tile (the old kernel paid this bookkeeping on every DP row).

@@ -15,12 +15,18 @@ def make_production_mesh(*, multi_pod: bool = False):
     cross-pod axis (lowest bandwidth → hierarchical gradient reduction)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
-    """Arbitrary mesh (tests / examples), e.g. ((1, 2), ("data", "model"))."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """Arbitrary mesh (tests / examples), e.g. ((1, 2), ("data", "model")).
+
+    Axes are ``Auto``: the models place arrays with
+    ``with_sharding_constraint``, which refuses the ``Explicit`` axes
+    ``jax.make_mesh`` builds by default."""
+    axes = tuple(axes)
+    return jax.make_mesh(tuple(shape), axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def get_mesh(shape=None, axis_names=None, *, devices=None):

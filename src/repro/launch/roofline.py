@@ -22,7 +22,24 @@ import dataclasses
 import re
 from typing import Optional
 
-V5E = dict(peak_flops=197e12, hbm_bw=819e9, ici_bw=50e9)
+#: Published per-chip peaks keyed by the ``device_kind`` JAX reports.
+#: Source: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16,
+#: 819 GB/s HBM, 1,600 Gbit/s interchip interconnect; ``ici_bw`` is the
+#: per-link share the dry-run accounting assumes).
+PEAKS = {
+    "TPU v5 lite": dict(peak_flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+#: The dry-run's production meshes are v5e pods.
+V5E = PEAKS["TPU v5 lite"]
+
+
+def chip_peaks(device_kind: str) -> dict:
+    """Peaks of the chip JAX reports as ``device_kind``; an unknown kind
+    is an error, never another chip's numbers."""
+    if device_kind not in PEAKS:
+        raise ValueError(f"no published peaks for device_kind "
+                         f"{device_kind!r}; known kinds: {sorted(PEAKS)}")
+    return PEAKS[device_kind]
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,

@@ -25,7 +25,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import tree_flatten_with_path
 from repro.configs import ArchConfig, ShapeSpec
 from repro.distributed import Axes
 from repro.models import RunConfig, decode_step, init_cache, init_lm, prefill
@@ -114,7 +113,7 @@ def _path_names(path):
 
 def tree_specs(tree, axes: Axes, mode: str):
     """PartitionSpec tree matching an eval_shape'd param/opt pytree."""
-    flat, treedef = tree_flatten_with_path(tree)
+    flat, treedef = jax.tree.flatten_with_path(tree)
     specs = [_leaf_spec(_path_names(p), l.shape, axes, mode)
              for p, l in flat]
     return treedef.unflatten(specs)
@@ -158,8 +157,8 @@ def batch_spec_tree(cfg, shape, axes: Axes):
         if name in ("tokens", "labels"):
             return P(dp, None) if len(sds.shape) == 2 else P(dp)
         return P(*([None] * len(sds.shape)))
-    flat, treedef = tree_flatten_with_path(input_specs(cfg, shape,
-                                                           RunConfig()))
+    flat, treedef = jax.tree.flatten_with_path(
+        input_specs(cfg, shape, RunConfig()))
     return treedef.unflatten([one(p, l) for p, l in flat])
 
 
@@ -212,7 +211,7 @@ def cache_spec_tree(cfg, shape, axes: Axes, cache_tree,
             return P(dp) if batch_shardable else P(None)
         return P(*([None] * rank))
 
-    flat, treedef = tree_flatten_with_path(cache_tree)
+    flat, treedef = jax.tree.flatten_with_path(cache_tree)
     return treedef.unflatten([one(p, l) for p, l in flat])
 
 

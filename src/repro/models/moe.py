@@ -34,8 +34,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
-
 from .layers import normal_init
 
 
@@ -180,7 +178,7 @@ def moe_mlp(params, cfg, x, axes=None):
         aux = lax.pmean(aux, all_axes)
         return out.reshape(bl, sl, d), aux
 
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(x_spec, P(), w_expert_spec, w_expert_spec, w_expert_spec),
         out_specs=(x_spec, P()),

@@ -82,6 +82,9 @@ class SearchResult:
     chunks_pruned_kim: int = 0    # skipped on the constant-time bound
     chunks_pruned_keogh: int = 0  # skipped on the envelope bound
     chunks_processed: int = 0     # dispatched to the DP
+    engine_impl: str = ""         # what scored the chunks: 'pallas' |
+                                  # 'rowscan' ('chunked' / 'sharded' when
+                                  # prune=False / mesh= stream instead)
 
     @property
     def chunks_pruned(self) -> int:
@@ -387,6 +390,9 @@ def _execute_search(req: SdtwRequest) -> SearchResult:
         dists, poss, starts = dists[0], poss[0], starts[0]
     return SearchResult(distances=dists, positions=poss, starts=starts,
                         chunk=used_chunk,
+                        engine_impl=("sharded" if mesh is not None
+                                     else engine_impl if prune
+                                     else "chunked"),
                         chunks_total=totals[0], chunks_pruned_kim=totals[1],
                         chunks_pruned_keogh=totals[2],
                         chunks_processed=totals[3])

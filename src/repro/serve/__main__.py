@@ -4,7 +4,7 @@ serving tier.
 Spawns N closed-loop client threads (each submits, waits for its
 result, repeats) against one ``Router``, then dumps the telemetry
 snapshot as JSON — the same numbers ``benchmarks/serve_bench.py`` turns
-into p50/p99/goodput rows.
+into p50/p99/goodput rows. Exits 1 when any client call failed.
 
 Example::
 
@@ -17,8 +17,11 @@ import argparse
 import json
 import sys
 import threading
+import traceback
 
 import numpy as np
+
+from repro.compile_cache import enable_compile_cache
 
 from .queue import QueueFull
 from .router import Router, RouterConfig
@@ -36,12 +39,15 @@ def run_load(router: Router, *, clients: int, requests: int, op: str,
              priority_classes: int = 1):
     """Closed-loop load: each client thread submits ``requests`` calls
     back-to-back (tenant ``client{ci}``, priority ``ci %
-    priority_classes``). Returns (completed, rejected)."""
+    priority_classes``). Returns (completed, rejected, failed): a call
+    that raised anything but ``QueueFull`` is counted as failed, with its
+    traceback on stderr."""
     rng = np.random.default_rng(seed)
     reference, query_pool = _make_workload(rng, nq=nq, qlen=qlen,
                                            reflen=reflen)
     completed = [0] * clients
     rejected = [0] * clients
+    failed = [0] * clients
 
     def client(ci: int):
         for r in range(requests):
@@ -59,6 +65,9 @@ def run_load(router: Router, *, clients: int, requests: int, op: str,
                 completed[ci] += 1
             except QueueFull:
                 rejected[ci] += 1
+            except Exception:                          # noqa: BLE001
+                failed[ci] += 1
+                traceback.print_exc()
 
     threads = [threading.Thread(target=client, args=(ci,))
                for ci in range(clients)]
@@ -66,7 +75,7 @@ def run_load(router: Router, *, clients: int, requests: int, op: str,
         t.start()
     for t in threads:
         t.join()
-    return sum(completed), sum(rejected)
+    return sum(completed), sum(rejected), sum(failed)
 
 
 def main(argv=None) -> int:
@@ -116,6 +125,7 @@ def main(argv=None) -> int:
                     help="also write the snapshot to this path")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     devices = args.devices
     if devices is not None and devices != "all":
         devices = int(devices)
@@ -128,7 +138,7 @@ def main(argv=None) -> int:
                           tenant_quota=args.tenant_quota,
                           dedup=not args.no_dedup)
     with Router(config) as router:
-        completed, rejected = run_load(
+        completed, rejected, failed = run_load(
             router, clients=args.clients, requests=args.requests,
             op=args.op, top_k=args.top_k, nq=args.nq, qlen=args.qlen,
             reflen=args.reflen, seed=args.seed,
@@ -137,12 +147,13 @@ def main(argv=None) -> int:
     snap["offered"] = args.clients * args.requests
     snap["client_completed"] = completed
     snap["client_rejected"] = rejected
+    snap["client_failed"] = failed
     out = json.dumps(snap, indent=2, sort_keys=True)
     print(out)
     if args.stats_json:
         with open(args.stats_json, "w") as f:
             f.write(out + "\n")
-    return 0
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":

@@ -203,22 +203,25 @@ class KernelCostModel:
         The candidate set stays deliberately small (it seeds the measured
         stage): block_q from 1 up to the batch (interpret) or the sublane
         multiple 8 (TPU), block_m the pow-2 ladder clamped to the
-        reference, both scan schemes, the backend's natural row_tile.
+        reference, the scan schemes the backend lowers (TPU: 'shift'
+        only), the backend's natural row_tile.
         """
         interpret = self.backend.name != "tpu"
         if interpret:
             bq_cands = sorted({bq for bq in (1, 2, 4, 8, 16, 32)
                                if bq <= max(1, nq)} | {min(32, max(1, nq))})
             rt = 1
+            schemes = ("assoc", "shift")
         else:
             bq_cands = [8, 16]
             rt = 8
+            schemes = ("shift",)    # Mosaic cannot lower 'assoc'
         bm_cands = sorted({min(bm, _pow2_bucket(m))
                            for bm in self.BLOCK_M_CANDIDATES})
         scored = []
         for bq in bq_cands:
             for bm in bm_cands:
-                for scheme in ("assoc", "shift"):
+                for scheme in schemes:
                     us = self.pallas_us(nq, n, m, bq, bm, scheme, rt,
                                         span=span)
                     if math.isfinite(us):

@@ -47,12 +47,18 @@ MEASURE_REPS = 3
 
 def canonical_backend(backend: Optional[str] = None) -> str:
     """Map a jax backend string to a tuning-backend name: 'tpu' keeps its
-    own calibration; everything else executes via XLA-CPU semantics
-    (pallas in interpret mode) and shares the 'interpret' family."""
+    own calibration; 'cpu' runs XLA-CPU (pallas in interpret mode) and is
+    the 'interpret' family. Any other backend has no constants and is
+    refused."""
     if backend is None:
         import jax
         backend = jax.default_backend()
-    return "tpu" if backend == "tpu" else "interpret"
+    if backend == "tpu":
+        return "tpu"
+    if backend in ("cpu", "interpret"):
+        return "interpret"
+    raise ValueError(f"no tuning backend for jax backend {backend!r}; "
+                     "expected 'cpu' or 'tpu'")
 
 
 @dataclasses.dataclass(frozen=True)

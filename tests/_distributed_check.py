@@ -50,7 +50,7 @@ KEY = jax.random.PRNGKey(0)
 
 if SDTW_MESH is None:
     from repro import checkpoint as ckpt
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.configs import get_arch
     from repro.distributed import Axes
     from repro.distributed.collectives import compressed_psum

@@ -735,3 +735,21 @@ def test_router_open_stream_and_stats(rng):
                                       np.asarray(d))
         snap = router.stats()
         assert snap.completed == snap.dispatches == 0
+
+
+def test_serve_driver_exits_nonzero_on_failed_calls(monkeypatch):
+    """``python -m repro.serve`` counts a client call that raised anything
+    but ``QueueFull`` as failed, and then exits 1 instead of 0."""
+    from repro.serve import __main__ as driver
+
+    class Broken:
+        def sdtw(self, *args, **kwargs):
+            raise ValueError("engine blew up")
+
+    assert driver.run_load(Broken(), clients=2, requests=3, op="sdtw",
+                           top_k=None, nq=1, qlen=8, reflen=32) == (0, 0, 6)
+    monkeypatch.setattr(driver, "enable_compile_cache", lambda: None)
+    for outcome, code in (((3, 0, 1), 1), ((4, 0, 0), 0)):
+        monkeypatch.setattr(driver, "run_load",
+                            lambda router, outcome=outcome, **kw: outcome)
+        assert driver.main(["--clients", "2", "--requests", "2"]) == code
