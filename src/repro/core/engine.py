@@ -99,6 +99,7 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .distances import accum_dtype, big
 from .request import SdtwRequest, StreamRequest, resolve_mesh
@@ -305,107 +306,118 @@ def _execute_sdtw(req: SdtwRequest):
             raise ValueError(
                 "explain=True is not supported for ragged query lists — "
                 "each bucket may dispatch differently; call per bucket")
-        return _sdtw_ragged(queries, reference, metric=metric, impl=impl,
-                            chunk=chunk, excl_lo=excl_lo, excl_hi=excl_hi,
-                            mesh=mesh, ref_axis=ref_axis, n_micro=n_micro,
-                            top_k=top_k,
-                            return_positions=return_positions,
-                            return_spans=return_spans, excl_zone=excl_zone,
-                            excl_mode=excl_mode,
-                            block_q=block_q, block_m=block_m, tune=tune)
+        with TraceAnnotation("engine.ragged"):
+            return _sdtw_ragged(
+                queries, reference, metric=metric, impl=impl, chunk=chunk,
+                excl_lo=excl_lo, excl_hi=excl_hi, mesh=mesh,
+                ref_axis=ref_axis, n_micro=n_micro, top_k=top_k,
+                return_positions=return_positions,
+                return_spans=return_spans, excl_zone=excl_zone,
+                excl_mode=excl_mode, block_q=block_q, block_m=block_m,
+                tune=tune)
 
-    queries = jnp.asarray(queries)
-    reference = jnp.asarray(reference)
-    single = queries.ndim == 1
-    if single:
-        queries = queries[None, :]
-    nq, n = queries.shape
-    m = reference.shape[0]
-    if qlens is not None:
-        qlens = jnp.asarray(qlens, jnp.int32)
-    dtype = str(jnp.result_type(queries, reference))
+    # Host work up to the backend call (the query's host-to-device copy,
+    # the dispatch decision, tuning lookups) is ``engine.prepare``; the
+    # call itself, which returns arrays the device has yet to compute,
+    # is ``engine.launch``.
+    with TraceAnnotation("engine.prepare"):
+        queries = jnp.asarray(queries)
+        reference = jnp.asarray(reference)
+        single = queries.ndim == 1
+        if single:
+            queries = queries[None, :]
+        nq, n = queries.shape
+        m = reference.shape[0]
+        if qlens is not None:
+            qlens = jnp.asarray(qlens, jnp.int32)
+        dtype = str(jnp.result_type(queries, reference))
 
-    if tune == "measure":
-        # Measured refinement must never run inside a trace — resolve the
-        # bucket eagerly here (once per process per bucket; the LRU and
-        # the process table absorb repeats), then every downstream
-        # consultation is a table hit.
-        from repro.tune import resolve as _tune_resolve
-        _tune_resolve(nq, n, m, metric=metric, dtype=dtype,
-                      mode="measure", span=return_spans)
+        if tune == "measure":
+            # Measured refinement must never run inside a trace — resolve
+            # the bucket eagerly here (once per process per bucket; the LRU
+            # and the process table absorb repeats), then every downstream
+            # consultation is a table hit.
+            from repro.tune import resolve as _tune_resolve
+            _tune_resolve(nq, n, m, metric=metric, dtype=dtype,
+                          mode="measure", span=return_spans)
 
-    has_excl = excl_lo is not None or excl_hi is not None
-    if impl == "auto":
-        impl, source, reason, candidates = choose_impl_explained(
-            nq, n, m, mesh=mesh, chunk=chunk, has_exclusion=has_excl,
-            top_k=top_k, tune=tune, metric=metric, dtype=dtype)
-    else:
-        source, reason, candidates = (
-            "explicit", "impl forced by the caller", ())
-    if impl == "pallas" and has_excl:
-        raise ValueError("the pallas kernel does not support exclusion "
-                         "zones; use impl='rowscan' or 'chunked'")
-
-    config: dict = {}
-    if impl in ("rowscan", "wavefront"):
-        lo = _normalize_excl(excl_lo, nq) if has_excl else None
-        hi = _normalize_excl(excl_hi, nq) if has_excl else None
-        out = sdtw_batch(queries, reference, qlens, metric, impl, lo, hi,
-                         return_positions=return_positions,
-                         return_spans=return_spans)
-    elif impl == "pallas":
-        from repro.kernels.sdtw import (interpret_mode, resolve_blocks,
-                                        sdtw_pallas)
-        if explain:
-            rbq, rbm, rscheme, rrt = resolve_blocks(
-                nq, m, block_q, block_m, None, None, interpret_mode(), n=n,
-                metric=metric, dtype=dtype, tune=tune, span=return_spans)
-            config = {"block_q": rbq, "block_m": rbm,
-                      "scan_scheme": rscheme, "row_tile": rrt}
-        if chunk is None:
-            out = sdtw_pallas(queries, reference, qlens, metric,
-                              block_q=block_q, block_m=block_m,
-                              return_positions=return_positions,
-                              return_spans=return_spans, tune=tune)
+        has_excl = excl_lo is not None or excl_hi is not None
+        if impl == "auto":
+            impl, source, reason, candidates = choose_impl_explained(
+                nq, n, m, mesh=mesh, chunk=chunk, has_exclusion=has_excl,
+                top_k=top_k, tune=tune, metric=metric, dtype=dtype)
         else:
-            out = _pallas_streamed(queries, reference, qlens, metric, chunk,
-                                   block_q, block_m, return_positions,
-                                   return_spans, tune=tune)
-    elif impl == "chunked":
-        if chunk is None and tune != "off":
-            from repro.tune import tuned_chunk
-            chunk = tuned_chunk(nq, n, m, metric=metric, dtype=dtype,
-                                mode=tune)
-        config = {"chunk": chunk or DEFAULT_CHUNK}
-        out = sdtw_chunked(queries, reference, qlens, metric,
-                           chunk or DEFAULT_CHUNK,
-                           _normalize_excl(excl_lo, nq),
-                           _normalize_excl(excl_hi, nq),
-                           top_k=top_k, excl_zone=excl_zone,
-                           return_positions=return_positions,
-                           return_spans=return_spans, excl_mode=excl_mode)
-    else:  # sharded
-        from repro.distributed.sdtw_sharded import sdtw_sharded
-        if n_micro is None and tune != "off" and mesh is not None:
-            from repro.tune import resolve_n_micro
-            sizes = dict(mesh.shape)
-            n_mp = int(sizes.pop(ref_axis, 1))
-            n_dp = int(np.prod(list(sizes.values()))) if sizes else 1
-            n_micro = resolve_n_micro(nq, n_dp, n_mp, n=n, m=m,
-                                      metric=metric, dtype=dtype,
-                                      mode=tune)
-        config = {"chunk": chunk or DEFAULT_CHUNK, "n_micro": n_micro}
-        out = sdtw_sharded(queries, reference, qlens, metric=metric,
-                           mesh=mesh, axis=ref_axis, n_micro=n_micro,
-                           chunk=chunk or DEFAULT_CHUNK,
-                           excl_lo=_normalize_excl(excl_lo, nq),
-                           excl_hi=_normalize_excl(excl_hi, nq),
-                           top_k=top_k, excl_zone=excl_zone,
-                           return_positions=return_positions,
-                           return_spans=return_spans, excl_mode=excl_mode)
-    if single:
-        out = (tuple(o[0] for o in out) if isinstance(out, tuple)
-               else out[0])
+            source, reason, candidates = (
+                "explicit", "impl forced by the caller", ())
+        if impl == "pallas" and has_excl:
+            raise ValueError("the pallas kernel does not support exclusion "
+                             "zones; use impl='rowscan' or 'chunked'")
+
+        config: dict = {}
+        if impl in ("rowscan", "wavefront"):
+            lo = _normalize_excl(excl_lo, nq) if has_excl else None
+            hi = _normalize_excl(excl_hi, nq) if has_excl else None
+            launch = functools.partial(
+                sdtw_batch, queries, reference, qlens, metric, impl, lo, hi,
+                return_positions=return_positions, return_spans=return_spans)
+        elif impl == "pallas":
+            from repro.kernels.sdtw import (interpret_mode, resolve_blocks,
+                                            sdtw_pallas)
+            if explain:
+                rbq, rbm, rscheme, rrt = resolve_blocks(
+                    nq, m, block_q, block_m, None, None, interpret_mode(),
+                    n=n, metric=metric, dtype=dtype, tune=tune,
+                    span=return_spans)
+                config = {"block_q": rbq, "block_m": rbm,
+                          "scan_scheme": rscheme, "row_tile": rrt}
+            if chunk is None:
+                launch = functools.partial(
+                    sdtw_pallas, queries, reference, qlens, metric,
+                    block_q=block_q, block_m=block_m,
+                    return_positions=return_positions,
+                    return_spans=return_spans, tune=tune)
+            else:
+                launch = functools.partial(
+                    _pallas_streamed, queries, reference, qlens, metric,
+                    chunk, block_q, block_m, return_positions, return_spans,
+                    tune=tune)
+        elif impl == "chunked":
+            if chunk is None and tune != "off":
+                from repro.tune import tuned_chunk
+                chunk = tuned_chunk(nq, n, m, metric=metric, dtype=dtype,
+                                    mode=tune)
+            config = {"chunk": chunk or DEFAULT_CHUNK}
+            launch = functools.partial(
+                sdtw_chunked, queries, reference, qlens, metric,
+                chunk or DEFAULT_CHUNK, _normalize_excl(excl_lo, nq),
+                _normalize_excl(excl_hi, nq), top_k=top_k,
+                excl_zone=excl_zone, return_positions=return_positions,
+                return_spans=return_spans, excl_mode=excl_mode)
+        else:  # sharded
+            from repro.distributed.sdtw_sharded import sdtw_sharded
+            if n_micro is None and tune != "off" and mesh is not None:
+                from repro.tune import resolve_n_micro
+                sizes = dict(mesh.shape)
+                n_mp = int(sizes.pop(ref_axis, 1))
+                n_dp = int(np.prod(list(sizes.values()))) if sizes else 1
+                n_micro = resolve_n_micro(nq, n_dp, n_mp, n=n, m=m,
+                                          metric=metric, dtype=dtype,
+                                          mode=tune)
+            config = {"chunk": chunk or DEFAULT_CHUNK, "n_micro": n_micro}
+            launch = functools.partial(
+                sdtw_sharded, queries, reference, qlens, metric=metric,
+                mesh=mesh, axis=ref_axis, n_micro=n_micro,
+                chunk=chunk or DEFAULT_CHUNK,
+                excl_lo=_normalize_excl(excl_lo, nq),
+                excl_hi=_normalize_excl(excl_hi, nq),
+                top_k=top_k, excl_zone=excl_zone,
+                return_positions=return_positions,
+                return_spans=return_spans, excl_mode=excl_mode)
+    with TraceAnnotation("engine.launch", impl=impl, nq=nq):
+        out = launch()
+        if single:
+            out = (tuple(o[0] for o in out) if isinstance(out, tuple)
+                   else out[0])
     if explain:
         from repro.tune import DispatchDecision
         score = candidates[0][1] if candidates else None

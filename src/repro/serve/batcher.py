@@ -42,10 +42,11 @@ import dataclasses
 import hashlib
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.request import SdtwRequest
 
-from .telemetry import RequestTrace
+from .telemetry import RequestTrace, request_ids
 
 
 @dataclasses.dataclass
@@ -205,13 +206,13 @@ def _deliver_one(p: Pending, result, exc, telemetry):
     fut = p.future
     if fut.cancelled():
         if telemetry is not None:
-            telemetry.record_cancelled(p.trace)
+            telemetry.record_cancelled()
         return
     if fut.done():
         return                          # answered elsewhere (close race)
     if not fut.set_running_or_notify_cancel():
         if telemetry is not None:       # cancelled between the checks
-            telemetry.record_cancelled(p.trace)
+            telemetry.record_cancelled()
         return
     p.trace.mark_complete(error=exc is not None)
     if telemetry is not None:
@@ -236,12 +237,11 @@ def execute_group(group: list, telemetry=None):
     answered). Deduplicated twins receive the *same* result object as
     their surviving member. Each trace is completed and recorded
     *before* its future resolves, so a client that has its result is
-    guaranteed to already be counted in the stats snapshot."""
-    n_queries = sum(len(p.entries) for p in group)
-    n_members = sum(1 for _ in group_members(group))
-    for p in group_members(group):
-        p.trace.mark_dispatch(batch_requests=n_members,
-                              batch_queries=n_queries)
+    guaranteed to already be counted in the stats snapshot. Delivery
+    runs in the ``serve.deliver`` span."""
+    members = list(group_members(group))
+    for p in members:
+        p.trace.mark_dispatch()
 
     def deliver(p, result=None, exc=None):
         for member in (p, *(p.dupes or ())):
@@ -249,15 +249,22 @@ def execute_group(group: list, telemetry=None):
 
     try:
         if len(group) == 1:
-            deliver(group[0], group[0].request.run())
-            return
-        merged = [e for p in group for e in p.entries]
-        base = group[0].request
-        res = dataclasses.replace(base, queries=merged, qlens=None).run()
-        i0 = 0
-        for p in group:
-            i1 = i0 + len(p.entries)
-            deliver(p, _slice_result(res, i0, i1, p.single))
-            i0 = i1
+            res = group[0].request.run()
+        else:
+            merged = [e for p in group for e in p.entries]
+            base = group[0].request
+            res = dataclasses.replace(base, queries=merged, qlens=None).run()
+        for p in members:
+            p.trace.mark_launched()
+        with TraceAnnotation("serve.deliver",
+                             req=request_ids(p.trace for p in members)):
+            if len(group) == 1:
+                deliver(group[0], res)
+                return
+            i0 = 0
+            for p in group:
+                i1 = i0 + len(p.entries)
+                deliver(p, _slice_result(res, i0, i1, p.single))
+                i0 = i1
     except Exception as exc:                           # noqa: BLE001
         fail_group(group, exc, telemetry=telemetry)

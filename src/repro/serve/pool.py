@@ -53,7 +53,10 @@ import collections
 import queue as _stdqueue
 import threading
 
+from jax.profiler import TraceAnnotation
+
 from . import batcher
+from .telemetry import request_ids
 
 __all__ = ["DevicePool", "clear_affinity_cache", "pick_device"]
 
@@ -256,13 +259,20 @@ class DevicePool:
             if task is None:
                 return
             group, telemetry, cold_shape = task
+            members = list(batcher.group_members(group))
             try:
-                if dev is None:
-                    batcher.execute_group(group, telemetry=telemetry)
-                else:
-                    import jax
-                    with jax.default_device(dev):
+                with TraceAnnotation(
+                        "serve.execute",
+                        req=request_ids(p.trace for p in members),
+                        requests=len(members),
+                        queries=sum(len(p.entries) for p in group)):
+                    if dev is None:
                         batcher.execute_group(group, telemetry=telemetry)
+                    else:
+                        import jax
+                        with jax.default_device(dev):
+                            batcher.execute_group(group,
+                                                  telemetry=telemetry)
             except Exception as exc:                     # noqa: BLE001
                 # execute_group never raises by contract; this is a
                 # last-ditch guard so a pool bug can never orphan

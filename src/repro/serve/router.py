@@ -42,11 +42,13 @@ from __future__ import annotations
 
 import concurrent.futures
 import dataclasses
+import itertools
 import threading
 import time
 from typing import Any, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.request import SdtwRequest, StreamRequest
 from repro.search.cache import EnvelopeCache
@@ -55,7 +57,8 @@ from . import batcher
 from .pool import DevicePool
 from .queue import AdmissionQueue, QueueFull
 from .sessions import StreamSessionPool
-from .telemetry import RequestTrace, StatsSnapshot, Telemetry
+from .telemetry import (RequestTrace, StatsSnapshot, Telemetry,
+                        request_ids)
 
 __all__ = ["Router", "RouterConfig", "QueueFull"]
 
@@ -115,6 +118,7 @@ class Router:
                                      aging_s=config.aging_s)
         self._pool = DevicePool(config.devices)
         self._dispatch_lock = threading.Lock()
+        self._ids = itertools.count(1)
         self._closed = False
         self._thread = None
         if config.auto_dispatch:
@@ -136,41 +140,43 @@ class Router:
         error messages; a full queue raises ``QueueFull`` (or, under the
         reject policy, sheds a pending lower-priority request — its
         future fails with ``QueueFull`` instead)."""
-        if self._closed:
-            raise RuntimeError("router is closed")
-        if request is None:
-            request = SdtwRequest.from_kwargs(**kwargs)
-        elif kwargs:
-            raise ValueError("pass an SdtwRequest or kwargs, not both")
-        request.validate()
-        if getattr(request, "explain", False):
-            raise ValueError(
-                "explain=True is not servable: a coalesced batch has no "
-                "single per-request dispatch decision; call engine.sdtw "
-                "directly for the DispatchDecision")
-        if request.op == "search_topk" and request.cache is None:
-            request = dataclasses.replace(request, cache=self.cache)
-        trace = RequestTrace(op=request.op, nq=_request_nq(request))
-        fut = concurrent.futures.Future()
-        pending = batcher.Pending(request=request, future=fut, trace=trace)
-        try:
-            depth, shed = self._queue.put(pending,
-                                          priority=request.priority,
-                                          tenant=request.tenant,
-                                          weight=trace.nq)
-        except QueueFull:
-            self.telemetry.record_reject()
-            raise
-        if shed is not None:
-            self._fail_pending(
-                shed,
-                QueueFull("request shed from the admission queue by a "
-                          "higher-priority arrival; retry later or raise "
-                          "max_queue"))
-            self.telemetry.record_shed()
-        trace.queue_depth = depth
-        self.telemetry.observe_depth(depth)
-        return fut
+        req_id = next(self._ids)
+        with TraceAnnotation("serve.submit", req=req_id):
+            if self._closed:
+                raise RuntimeError("router is closed")
+            if request is None:
+                request = SdtwRequest.from_kwargs(**kwargs)
+            elif kwargs:
+                raise ValueError("pass an SdtwRequest or kwargs, not both")
+            request.validate()
+            if getattr(request, "explain", False):
+                raise ValueError(
+                    "explain=True is not servable: a coalesced batch has no "
+                    "single per-request dispatch decision; call engine.sdtw "
+                    "directly for the DispatchDecision")
+            if request.op == "search_topk" and request.cache is None:
+                request = dataclasses.replace(request, cache=self.cache)
+            trace = RequestTrace(op=request.op, nq=_request_nq(request),
+                                 req_id=req_id)
+            fut = concurrent.futures.Future()
+            pending = batcher.Pending(request=request, future=fut, trace=trace)
+            try:
+                depth, shed = self._queue.put(pending,
+                                              priority=request.priority,
+                                              tenant=request.tenant,
+                                              weight=trace.nq)
+            except QueueFull:
+                self.telemetry.record_reject()
+                raise
+            if shed is not None:
+                self._fail_pending(
+                    shed,
+                    QueueFull("request shed from the admission queue by a "
+                              "higher-priority arrival; retry later or raise "
+                              "max_queue"))
+                self.telemetry.record_shed()
+            self.telemetry.observe_depth(depth)
+            return fut
 
     @staticmethod
     def _fail_pending(pending, exc):
@@ -252,12 +258,16 @@ class Router:
         the pool has answered every submitted group — the deterministic
         manual-mode workhorse. ``wait=False`` (the dispatch loop) lets
         the next window accrue while devices are still computing."""
-        with self._dispatch_lock:
+        with self._dispatch_lock, TraceAnnotation("serve.group") as span:
             window = self._queue.drain(self.config.max_window_requests)
             n = len(window)
+            for p in window:
+                p.trace.mark_drain()
             if window:
                 groups = batcher.group_window(window,
                                               dedup=self.config.dedup)
+                span.set_metadata(req=request_ids(p.trace for p in window),
+                                  groups=len(groups))
                 for grp in groups:
                     n_members = sum(1 for _ in batcher.group_members(grp))
                     self.telemetry.record_dispatch(
@@ -278,10 +288,11 @@ class Router:
         while not self._closed:
             if not self._queue.wait_nonempty(timeout=0.1):
                 continue
-            t_open = time.monotonic()
-            full = self._queue.wait_weight(cfg.window_full_queries,
-                                           t_open + window)
-            duration = time.monotonic() - t_open
+            with TraceAnnotation("serve.window"):
+                t_open = time.monotonic()
+                full = self._queue.wait_weight(cfg.window_full_queries,
+                                               t_open + window)
+                duration = time.monotonic() - t_open
             n = self.drain(wait=False)
             self.telemetry.record_window(duration_s=duration,
                                          closed_early=full)

@@ -1,18 +1,40 @@
 """Per-request traces and router-level stats for the serve tier.
 
-Every admitted request carries a ``RequestTrace`` through its life:
-enqueue → dispatch (when the batcher pulled it into a merged engine
-call) → complete (result or error delivered to the client future). The
-``Telemetry`` aggregator folds finished traces into a running store the
-router exposes as an immutable ``StatsSnapshot`` — the numbers NATSA-
-style serving cares about: queue depth seen at admission, microbatch
-occupancy (how many client requests each engine dispatch amortized),
-and the latency split between waiting and computing.
+Every admitted request carries a ``RequestTrace`` through five stamps,
+each taken beside the profiler span of the same step:
 
-Memory is bounded: the latency/queue-wait/batch-size sample stores are
-ring buffers of the most recent ``window`` observations (default 8192),
-so a long-running router neither leaks nor re-sorts an ever-growing
-list at ``snapshot()``. Snapshot semantics under the bound:
+  ==============  ============================================  ===========
+  stamp           taken when                                    thread
+  ==============  ============================================  ===========
+  ``t_enqueue``   ``Router.submit`` admits it                   client
+  ``t_drain``     ``Router.drain`` takes it off the queue       dispatcher
+  ``t_dispatch``  a pool worker picks its group up              pool worker
+  ``t_launched``  the group's engine call has returned          pool worker
+  ``t_complete``  its future is resolved                        pool worker
+  ==============  ============================================  ===========
+
+The four stages between them are the admission wait (queue and
+coalescing window), the pool wait (for the worker, busy with earlier
+groups), the engine's host work (``engine.prepare`` and
+``engine.launch``; the device runs asynchronously), and delivery
+(slicing the merged result, resolving futures). ``Telemetry`` keeps an
+exact running sum per stage, so ``StatsSnapshot``'s four
+``mean_*_us`` stage means add up to ``mean_latency_us``.
+
+The same boundaries are ``jax.profiler.TraceAnnotation`` spans, on the
+clock of the device operations in a profiler trace: ``serve.submit``
+(admission, client thread), ``serve.window`` (the coalescing window,
+dispatcher), ``serve.group`` (drain, grouping, hand-off to the pool),
+``serve.execute`` (one group on a pool worker), inside it the engine's
+``engine.ragged``, ``engine.prepare`` and ``engine.launch``, and
+``serve.deliver``. Each carries ``req=``, the ``+``-joined ids of the
+requests it serves, where it knows them. With the profiler off a span
+costs under a microsecond of host time.
+
+Memory is bounded: the latency/queue-wait sample stores are ring
+buffers of the most recent ``window`` observations (default 8192), so a
+long-running router neither leaks nor re-sorts an ever-growing list at
+``snapshot()``. Snapshot semantics under the bound:
 
   * counters (``completed``, ``errors``, ``rejected``, ``shed``,
     ``deduped``, …) and the ``mean_*`` fields are exact over the
@@ -20,7 +42,8 @@ list at ``snapshot()``. Snapshot semantics under the bound:
   * the ``p50_*``/``p99_*`` percentiles are computed over the last
     ``window`` samples only (``latency_samples`` reports how many are
     currently held) — a sliding-window view, which is what a latency
-    SLO wants anyway.
+    SLO wants anyway. ``p50_queue_us`` runs from ``t_enqueue`` to
+    ``t_dispatch``: admission wait plus pool wait.
 
 All timestamps are ``time.monotonic()`` floats (seconds); snapshots
 report microseconds, matching the benchmark harness row units.
@@ -50,23 +73,33 @@ def percentile(values, q: float) -> float:
     return float(vs[idx])
 
 
+def request_ids(traces) -> str:
+    """The ``req=`` value of a span serving ``traces``: their ids joined
+    by ``+`` (a lone id reads back from the trace as an integer)."""
+    return "+".join(str(t.req_id) for t in traces)
+
+
 @dataclasses.dataclass
 class RequestTrace:
-    """Lifecycle timestamps + context for one admitted request."""
+    """Lifecycle timestamps of one admitted request (module docstring)."""
     op: str
     nq: int                          # queries carried by this request
+    req_id: int = 0                  # the router's id, the spans' ``req``
     t_enqueue: float = dataclasses.field(default_factory=_now)
+    t_drain: Optional[float] = None
     t_dispatch: Optional[float] = None
+    t_launched: Optional[float] = None
     t_complete: Optional[float] = None
-    queue_depth: int = 0             # depth observed at admission
-    batch_requests: int = 0          # requests sharing the merged call
-    batch_queries: int = 0           # total queries in the merged call
     error: bool = False
 
-    def mark_dispatch(self, *, batch_requests: int, batch_queries: int):
+    def mark_drain(self):
+        self.t_drain = _now()
+
+    def mark_dispatch(self):
         self.t_dispatch = _now()
-        self.batch_requests = batch_requests
-        self.batch_queries = batch_queries
+
+    def mark_launched(self):
+        self.t_launched = _now()
 
     def mark_complete(self, *, error: bool = False):
         self.t_complete = _now()
@@ -83,6 +116,18 @@ class RequestTrace:
         if self.t_complete is None:
             return float("nan")
         return (self.t_complete - self.t_enqueue) * 1e6
+
+    def stages_us(self) -> tuple:
+        """Admission wait, pool wait, engine and delivery, in µs. A stamp
+        the request never reached (an engine call that raised stamps no
+        ``t_launched``) takes the next one's time, so the skipped stage
+        reads 0 and the four always add up to ``latency_us``."""
+        stamps = [self.t_enqueue, self.t_drain, self.t_dispatch,
+                  self.t_launched, self.t_complete]
+        for i in range(len(stamps) - 2, 0, -1):
+            if stamps[i] is None:
+                stamps[i] = stamps[i + 1]
+        return tuple((b - a) * 1e6 for a, b in zip(stamps, stamps[1:]))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -103,6 +148,10 @@ class StatsSnapshot:
     p50_latency_us: float
     p99_latency_us: float
     mean_latency_us: float          # exact (running sum, not windowed)
+    mean_admit_wait_us: float       # exact stage means (module
+    mean_pool_wait_us: float        # docstring); the four add up to
+    mean_engine_us: float           # mean_latency_us
+    mean_deliver_us: float
     p50_queue_us: float
     max_queue_depth: int
     mean_batch_requests: float      # requests per dispatch (occupancy)
@@ -146,6 +195,7 @@ class Telemetry:
         self._queries = 0
         self._max_depth = 0
         self._latency_sum = 0.0
+        self._stage_sums = [0.0, 0.0, 0.0, 0.0]
         self._batch_requests_sum = 0
         self._batch_queries_sum = 0
         self._windows = 0
@@ -164,7 +214,7 @@ class Telemetry:
         with self._lock:
             self._shed += 1
 
-    def record_cancelled(self, trace: Optional[RequestTrace] = None):
+    def record_cancelled(self):
         with self._lock:
             self._cancelled += 1
 
@@ -191,6 +241,8 @@ class Telemetry:
             lat = trace.latency_us
             self._latency_sum += lat
             self._latencies.append(lat)
+            for i, us in enumerate(trace.stages_us()):
+                self._stage_sums[i] += us
             if trace.t_dispatch is not None:
                 self._queue_waits.append(trace.queue_us)
 
@@ -203,6 +255,10 @@ class Telemetry:
 
     def snapshot(self) -> StatsSnapshot:
         with self._lock:
+            n = self._completed
+            admit, pool, engine, deliver = (
+                [x / n for x in self._stage_sums] if n
+                else [float("nan")] * 4)
             return StatsSnapshot(
                 completed=self._completed,
                 errors=self._errors,
@@ -218,6 +274,10 @@ class Telemetry:
                 p99_latency_us=percentile(self._latencies, 99),
                 mean_latency_us=(self._latency_sum / self._completed
                                  if self._completed else float("nan")),
+                mean_admit_wait_us=admit,
+                mean_pool_wait_us=pool,
+                mean_engine_us=engine,
+                mean_deliver_us=deliver,
                 p50_queue_us=percentile(self._queue_waits, 50),
                 max_queue_depth=self._max_depth,
                 mean_batch_requests=(self._batch_requests_sum

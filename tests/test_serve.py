@@ -338,7 +338,7 @@ def test_telemetry_bounded_ring():
     tel = Telemetry(window=16)
     for _ in range(100):
         t = RequestTrace(op="sdtw", nq=2)
-        t.mark_dispatch(batch_requests=1, batch_queries=2)
+        t.mark_dispatch()
         t.mark_complete()
         tel.record_complete(t)
     snap = tel.snapshot()
