@@ -364,10 +364,14 @@ def _execute_sdtw(req: SdtwRequest):
             from repro.kernels.sdtw import (interpret_mode, resolve_blocks,
                                             sdtw_pallas)
             if explain:
+                # The blocks of the launch below: a reference past
+                # ``PALLAS_FUSED_MAX`` launches one chunk at a time.
+                m_launch = (chunk if chunk is not None
+                            and m > PALLAS_FUSED_MAX else m)
                 rbq, rbm, rscheme, rrt = resolve_blocks(
-                    nq, m, block_q, block_m, None, None, interpret_mode(),
-                    n=n, metric=metric, dtype=dtype, tune=tune,
-                    span=return_spans)
+                    nq, m_launch, block_q, block_m, None, None,
+                    interpret_mode(), n=n, metric=metric, dtype=dtype,
+                    tune=tune, span=return_spans)
                 config = {"block_q": rbq, "block_m": rbm,
                           "scan_scheme": rscheme, "row_tile": rrt}
             if chunk is None:

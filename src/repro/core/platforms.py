@@ -56,6 +56,50 @@ class PlatformModel:
 
 
 @dataclasses.dataclass(frozen=True)
+class CellPrice:
+    """The interpret-mode kernel's price, per grid tile: a per-row cost
+    and a per-cell cost with a scan-depth term of log2(block_q * block_m)
+    passes, each pass a memory sweep over the whole block, weighted by
+    the scan scheme."""
+    row_fixed_us: float      # per DP row per grid tile
+    elem_us: float           # per DP cell, scheme-independent base
+    pass_us: float           # per DP cell per scan *pass* (depth term)
+    scheme_mult: tuple       # (('shift', x), ('assoc', y)) pass-cost
+                             # multipliers
+
+    def scheme_cost_mult(self, scheme: str) -> float:
+        return dict(self.scheme_mult)[scheme]
+
+
+@dataclasses.dataclass(frozen=True)
+class RowChainPrice:
+    """The compiled kernel's price of one (grid tile, DP row), in us.
+
+    A DP row is a chain of dependent steps (``kernels/sdtw/sdtw.py``,
+    ``one_row``): one-hot lane picks of the query value and the boundary
+    entry, log2(block_m) Hillis-Steele lane-shift steps, and the pick of
+    the exit lane; the next row waits for it. The scan runs along lanes
+    only, and the ``block_q`` rows ride the same vector instructions, so
+    a row costs the larger of the chain's latency and the vector work it
+    carries:
+
+        max(lat_fixed_us + lat_step_us * log2(block_m),
+            vreg_step_us * (ceil(block_q/8) * ceil(block_m/128)
+                            * log2(block_m)
+                            + pick_vreg_steps * ceil(block_q/8)))
+
+    the latency times ``span_lat_mult`` and the work times
+    ``span_work_mult`` in span mode (the start lanes ride every step).
+    """
+    lat_fixed_us: float      # the picks and the exit lane, per row
+    lat_step_us: float       # one lane-shift step on the dependent chain
+    vreg_step_us: float      # one (8, 128) vreg through one scan step
+    pick_vreg_steps: float   # the picks' work per 8 queries, in vreg-steps
+    span_lat_mult: float     # span variant over plain: latency
+    span_work_mult: float    # span variant over plain: vector work
+
+
+@dataclasses.dataclass(frozen=True)
 class BackendModel:
     """Calibrated per-term execution-cost constants for one *execution
     backend of this repo* (as opposed to ``PlatformModel``, which models
@@ -66,11 +110,10 @@ class BackendModel:
     constants; the units are microseconds per the named event. The
     ``interpret`` constants were fitted to in-container XLA-CPU
     measurements of the committed bench shapes (see
-    ``repro/tune/tables/interpret.json`` provenance); the ``tpu``
-    constants (``TPU_BACKENDS``, keyed by device kind) are anchored to the
-    chip's published roofline (``launch/roofline.PEAKS``) and the kernel's
-    documented VMEM working set — on real TPU hardware the measured stage
-    (``tune='measure'``) refines them into the table.
+    ``repro/tune/tables/interpret.json`` provenance). The ``tpu``
+    constants (``TPU_BACKENDS``, keyed by device kind) price the compiled
+    kernel by its row chain, fitted to a block sweep on the chip; their
+    other terms are anchored to the chip's published roofline.
     """
     name: str                    # 'interpret' (XLA CPU) | 'tpu'
     call_fixed_us: float         # per-dispatch overhead of one jitted call
@@ -82,17 +125,11 @@ class BackendModel:
     cache_elems: int             # live-row working-set knee (elements);
                                  # beyond it scan_elem_us inflates
     tile_fixed_us: float         # per pallas grid cell (launch/fill)
-    pallas_row_fixed_us: float   # per DP row per pallas grid cell
-    pallas_elem_us: float        # per DP cell, scheme-independent base
-    pallas_pass_us: float        # per DP cell per scan *pass* (depth term)
-    scheme_mult: tuple           # (('shift', x), ('assoc', y)) pass-cost
-                                 # multipliers — which scan scheme is cheap
-                                 # is exactly what differs per backend
+    pallas_price: "CellPrice | RowChainPrice"
+                                 # the kernel's per-tile price: the two
+                                 # backends need opposite block shapes
     hbm_bw_bytes_per_s: float    # streaming bandwidth for the HBM term
     vmem_budget_words: int       # pallas per-config working-set cap
-
-    def scheme_cost_mult(self, scheme: str) -> float:
-        return dict(self.scheme_mult)[scheme]
 
 
 #: XLA-CPU (pallas interpret mode) — fitted to this container's measured
@@ -105,22 +142,29 @@ INTERPRET_BACKEND = BackendModel(
     name="interpret", call_fixed_us=500.0, row_step_fixed_us=60.0,
     scan_elem_us=0.027, wf_step_fixed_us=0.4, wf_elem_us=0.004,
     chunk_fixed_us=200.0, cache_elems=1 << 17, tile_fixed_us=150.0,
-    pallas_row_fixed_us=30.0, pallas_elem_us=0.01, pallas_pass_us=0.013,
-    scheme_mult=(("assoc", 1.0), ("shift", 1.6)),
+    pallas_price=CellPrice(row_fixed_us=30.0, elem_us=0.01, pass_us=0.013,
+                           scheme_mult=(("assoc", 1.0), ("shift", 1.6))),
     hbm_bw_bytes_per_s=20e9, vmem_budget_words=1 << 21)
 
-#: TPU v5e — roofline-anchored (819 GB/s HBM, ~16 MB VMEM/core): the
-#: vector unit runs the Hillis-Steele 'shift' scan (the only scheme Mosaic
-#: lowers), the per-cell cost is far below CPU, and the binding constraint
-#: is the VMEM working set ``block_q * (3*block_m + 3*N)`` words (span mode
-#: ``block_q * (6*block_m + 5*N)``). Never calibrated against a chip run.
+#: TPU v5e (819 GB/s HBM, ~16 MB VMEM/core): the vector unit runs the
+#: Hillis-Steele 'shift' scan (the only scheme Mosaic lowers), and the
+#: binding constraints on the block are the VMEM working set
+#: (``KernelCostModel.vmem_words``) and ``TPU_MAX_BLOCK_VREGS``. The
+#: row-chain price and ``tile_fixed_us`` are a least-squares fit (log
+#: error) to ``bench/block_sweep.py`` on one "TPU v5 lite" chip: 133
+#: configurations at the model's row_tile (block_q 8-256, block_m
+#: 128-4096, N 64, 120, 512 and 1536, plain, spans and last-row capture),
+#: rms error 9 %; within each (variant, N) the fitted pick runs within
+#: 4 % of the fastest block measured (PERF.md §6). The rowscan, wavefront
+#: and chunked terms are roofline-anchored guesses: on the TPU those
+#: regimes run only when forced.
 TPU_V5E_BACKEND = BackendModel(
     name="tpu", call_fixed_us=30.0, row_step_fixed_us=2.0,
     scan_elem_us=0.0004, wf_step_fixed_us=1.0, wf_elem_us=0.001,
-    chunk_fixed_us=40.0, cache_elems=1 << 21, tile_fixed_us=3.5,
-    pallas_row_fixed_us=0.05, pallas_elem_us=0.00005,
-    pallas_pass_us=0.00002,
-    scheme_mult=(("shift", 1.0), ("assoc", 1.4)),
+    chunk_fixed_us=40.0, cache_elems=1 << 21, tile_fixed_us=2.99,
+    pallas_price=RowChainPrice(lat_fixed_us=0.523, lat_step_us=0.0345,
+                               vreg_step_us=0.00352, pick_vreg_steps=7.67,
+                               span_lat_mult=1.14, span_work_mult=1.70),
     hbm_bw_bytes_per_s=819e9, vmem_budget_words=1 << 21)
 
 #: TPU cost constants keyed by the ``device_kind`` the chip reports

@@ -79,7 +79,7 @@ def interpret_mode(backend: str | None = None) -> bool:
 def resolve_blocks(b: int, m: int, block_q, block_m, scan_scheme, row_tile,
                    interpret: bool, *, n=None, metric: str = "abs_diff",
                    dtype: str = "int32", tune: str = "off",
-                   span: bool = False):
+                   span: bool = False, lastrow: bool = False):
     """Fill in the auto (None) kernel tuning knobs for this call shape.
 
     Returns ``(block_q, block_m, scan_scheme, row_tile)``. With
@@ -98,7 +98,8 @@ def resolve_blocks(b: int, m: int, block_q, block_m, scan_scheme, row_tile,
         from repro.tune import tuned_blocks
         tq, tm, ts, tr = tuned_blocks(
             b, m, n=int(n), backend="tpu" if not interpret else "interpret",
-            metric=metric, dtype=dtype, mode=tune, span=span)
+            metric=metric, dtype=dtype, mode=tune, span=span,
+            lastrow=lastrow)
         block_q = tq if block_q is None else block_q
         block_m = tm if block_m is None else block_m
         scan_scheme = ts if scan_scheme is None else scan_scheme
@@ -179,10 +180,11 @@ def sdtw_pallas(queries, reference, qlens=None, metric: str = "abs_diff",
     each DP row reading and writing the aligned 128-lane window that
     holds its entry, and the row loop keeps ~3 (plain) / ~6 (span)
     block-wide row vectors live (prev / captured-last-row / scan
-    temporaries, plus the start lanes). ``return_lastrow`` adds one
-    ``block_q · block_m`` output block (+ its int32 start lane in span
-    mode). Block shapes must be chosen so this fits (~16 MB VMEM on v5e);
-    the TPU defaults handle N ≤ 48K (plain) / N ≤ 24K (spans) comfortably.
+    temporaries, plus the start lanes). ``return_lastrow`` adds a
+    double-buffered ``block_q · block_m`` output block (+ its int32 start
+    lane in span mode). Block shapes must be chosen so this fits (~16 MB
+    VMEM on v5e); the TPU defaults handle N ≤ 48K (plain) / N ≤ 24K
+    (spans) comfortably.
     ``repro.tune.KernelCostModel.vmem_words`` prices candidates with this
     same formula, so any config the autotuner proposes fits by construction.
 
@@ -233,7 +235,7 @@ def sdtw_pallas(queries, reference, qlens=None, metric: str = "abs_diff",
         b, m, block_q, block_m, scan_scheme, row_tile, interpret,
         n=n, metric=metric,
         dtype=str(jnp.result_type(queries, reference)), tune=tune,
-        span=return_spans or track_start)
+        span=return_spans or track_start, lastrow=return_lastrow)
     if scan_scheme == "assoc" and not interpret:
         raise ValueError(
             "scan_scheme='assoc' does not compile for the TPU (Mosaic "

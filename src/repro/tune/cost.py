@@ -16,17 +16,22 @@ is priced in microseconds from the calibrated per-backend constants of
     and one boundary-column crossing per chunk: larger chunks amortize
     the N-row-steps-per-chunk overhead until the nq * chunk live rows
     fall out of cache.
-  * ``pallas``   — per grid cell: launch/fill (``tile_fixed``), a per-row
-    cost, and a per-cell cost with a scan-depth term — ``pass_us *
-    log2(block_q * block_m)`` scan passes, weighted by the backend's
-    scheme multiplier ('shift' Hillis-Steele is the cheap scheme on TPU,
-    the work-efficient 'assoc' in interpret mode) — plus the HBM
-    streaming term via ``launch.roofline.kernel_roofline`` and a padding
-    -waste factor for batches that do not fill ``block_q``.  Configs
-    whose VMEM working set ``block_q * (3*block_m + 3*N)`` words (span
-    mode ``block_q * (6*block_m + 5*N)``) exceed the backend budget are
-    rejected outright — the same formula ``kernels/sdtw/ops.py``
-    documents.
+  * ``pallas``   — per grid cell: launch/fill (``tile_fixed``), the
+    backend's ``pallas_price``, the HBM streaming term via
+    ``launch.roofline.kernel_roofline``, and the padding waste of
+    batches that do not fill ``block_q`` (padded tiles are paid in
+    full).  The two backends need opposite block shapes, so each has its
+    own price.  Interpret mode (``CellPrice``): a per-row cost plus a
+    per-cell cost with a scan depth of ``log2(block_q * block_m)``
+    passes, each a memory sweep over the block, weighted by the scan
+    scheme ('assoc' is the cheap one there) — small tiles win.  The
+    compiled TPU kernel (``RowChainPrice``): N DP rows per tile, each
+    the larger of its serial chain's latency (picks plus log2(block_m)
+    lane-shift steps) and the vregs its ``block_q`` rows carry through
+    those steps — tall blocks win until the vector work outweighs the
+    chain.  Configs whose VMEM working set ``vmem_words`` exceeds the
+    backend budget are rejected outright — the formula
+    ``kernels/sdtw/ops.py`` documents.
 
 The model's absolute numbers are rough; only its *ranking* is consumed
 (and CI validates the ranking against the measured rows of
@@ -38,7 +43,7 @@ import dataclasses
 import math
 from typing import Optional
 
-from repro.core.platforms import BackendModel, backend_model
+from repro.core.platforms import BackendModel, RowChainPrice, backend_model
 
 #: Knobs a tuning decision may set.  ``None`` fields mean "not applicable
 #: to the chosen impl" — the oracle only ever fills knobs the caller left
@@ -89,6 +94,13 @@ class KernelCostModel:
     CHUNK_CANDIDATES = (4096, 8192, 16384, 32768, 65536, 131072)
     #: reference-tile sizes the pallas oracle ranks (clamped to shape).
     BLOCK_M_CANDIDATES = (256, 512, 1024, 2048, 4096)
+    #: query-block sizes the compiled (TPU) kernel ranks, capped at the
+    #: batch rounded up to the sublane multiple 8.
+    TPU_BLOCK_Q_CANDIDATES = (8, 16, 32, 64, 128, 256)
+    #: the most vregs one live (block_q, block_m) array of a TPU candidate
+    #: may span: the block sweep measured up to 64, where the cells per
+    #: second had stopped growing, and compile time grows with it.
+    TPU_MAX_BLOCK_VREGS = 64
 
     def __init__(self, backend: "str | BackendModel" = "interpret"):
         self.backend = (backend if isinstance(backend, BackendModel)
@@ -98,14 +110,17 @@ class KernelCostModel:
 
     @staticmethod
     def vmem_words(block_q: int, block_m: int, n: int,
-                   span: bool = False) -> int:
+                   span: bool = False, lastrow: bool = False) -> int:
         """Accumulator words live per pallas grid cell — identical to the
         formula in the ``sdtw_pallas`` docstring (boundary column in
         persistent scratch + ~3 (plain) / ~6 (span) live row vectors,
-        span mode adding the int32 start lanes)."""
-        if span:
-            return block_q * (6 * block_m + 5 * n)
-        return block_q * (3 * block_m + 3 * n)
+        span mode adding the int32 start lanes), plus the double-buffered
+        ``return_lastrow`` output block and its start lane."""
+        words = (block_q * (6 * block_m + 5 * n) if span
+                 else block_q * (3 * block_m + 3 * n))
+        if lastrow:
+            words += 2 * block_q * block_m * (2 if span else 1)
+        return words
 
     # -- per-regime cost (microseconds) ---------------------------------
 
@@ -136,21 +151,16 @@ class KernelCostModel:
 
     def pallas_us(self, nq: int, n: int, m: int, block_q: int,
                   block_m: int, scan_scheme: str, row_tile: int,
-                  span: bool = False) -> float:
+                  span: bool = False, lastrow: bool = False) -> float:
         """One pallas launch over the full grid; ``inf`` when the config
         busts the VMEM budget (never a candidate)."""
         be = self.backend
-        if self.vmem_words(block_q, block_m, n, span) \
+        if self.vmem_words(block_q, block_m, n, span, lastrow) \
                 > be.vmem_budget_words:
             return float("inf")
         q_tiles = -(-nq // block_q)
         m_tiles = -(-max(m, block_m) // block_m)
         tiles = q_tiles * m_tiles
-        # Padding waste: cells are computed on the padded grid.
-        cells = (q_tiles * block_q) * n * (m_tiles * block_m)
-        passes = math.log2(max(2, block_q * block_m))
-        elem = be.pallas_elem_us + be.pallas_pass_us * passes \
-            * be.scheme_cost_mult(scan_scheme)
         # HBM streaming: the reference is re-read once per query tile,
         # queries once per reference tile, boundary column stays in VMEM
         # scratch (free); 4-byte accumulator words.
@@ -159,10 +169,43 @@ class KernelCostModel:
         hbm_us = kernel_roofline(
             0, hbm_bytes, cells_per_s=1.0,
             hbm_bw=be.hbm_bw_bytes_per_s)[0] * 1e6
+        price = be.pallas_price
+        if isinstance(price, RowChainPrice):
+            # Compiled kernel: a tile pays its row chain N times; padding
+            # waste is the padded tiles themselves.
+            return (be.call_fixed_us + tiles * be.tile_fixed_us
+                    + tiles * n * self.row_chain_us(block_q, block_m,
+                                                    span)
+                    + hbm_us)
+        # Padding waste: cells are computed on the padded grid.
+        cells = (q_tiles * block_q) * n * (m_tiles * block_m)
+        passes = math.log2(max(2, block_q * block_m))
+        elem = price.elem_us + price.pass_us * passes \
+            * price.scheme_cost_mult(scan_scheme)
         rt_mult = 1.0 + 0.02 * max(0, 8 // max(1, row_tile) - 1)
         return (be.call_fixed_us + tiles * be.tile_fixed_us
-                + tiles * n * be.pallas_row_fixed_us * rt_mult
+                + tiles * n * price.row_fixed_us * rt_mult
                 + cells * elem + hbm_us)
+
+    @staticmethod
+    def block_vregs(block_q: int, block_m: int) -> int:
+        """(8, 128) vregs one (block_q, block_m) int32 array spans."""
+        return -(-block_q // 8) * -(-block_m // 128)
+
+    def row_chain_us(self, block_q: int, block_m: int,
+                     span: bool = False) -> float:
+        """Compiled-kernel time of one (tile, DP row): the larger of the
+        row's dependent-chain latency and its vector work (see
+        ``repro.core.platforms.RowChainPrice``)."""
+        c = self.backend.pallas_price
+        steps = math.log2(block_m)
+        latency = c.lat_fixed_us + c.lat_step_us * steps
+        work = c.vreg_step_us * (self.block_vregs(block_q, block_m) * steps
+                                 + c.pick_vreg_steps * -(-block_q // 8))
+        if span:
+            latency *= c.span_lat_mult
+            work *= c.span_work_mult
+        return max(latency, work)
 
     # -- candidate enumeration / ranking --------------------------------
 
@@ -197,46 +240,60 @@ class KernelCostModel:
         return self.chunk_candidates(nq, n, m)[0][0]
 
     def pallas_candidates(self, nq: int, n: int, m: int,
-                          span: bool = False) -> list:
+                          span: bool = False, lastrow: bool = False) -> list:
         """Ranked ``[((block_q, block_m, scheme, row_tile), us), ...]``.
 
         The candidate set stays deliberately small (it seeds the measured
-        stage): block_q from 1 up to the batch (interpret) or the sublane
-        multiple 8 (TPU), block_m the pow-2 ladder clamped to the
-        reference, the scan schemes the backend lowers (TPU: 'shift'
-        only), the backend's natural row_tile.
+        stage): block_m the pow-2 ladder clamped to the reference, the
+        scan schemes the backend lowers. Interpret mode: block_q from 1
+        up to the batch, no row unrolling. The compiled kernel: block_q
+        the sublane multiples of ``TPU_BLOCK_Q_CANDIDATES`` up to the
+        batch rounded up to 8, 'shift' only, and ``tpu_row_tile`` rows
+        unrolled. Configs that bust the VMEM budget are dropped.
         """
-        interpret = self.backend.name != "tpu"
-        if interpret:
+        compiled = isinstance(self.backend.pallas_price, RowChainPrice)
+        if compiled:
+            cap = -(-max(1, nq) // 8) * 8
+            bq_cands = [bq for bq in self.TPU_BLOCK_Q_CANDIDATES
+                        if bq <= cap]
+            schemes = ("shift",)    # Mosaic cannot lower 'assoc'
+        else:
             bq_cands = sorted({bq for bq in (1, 2, 4, 8, 16, 32)
                                if bq <= max(1, nq)} | {min(32, max(1, nq))})
-            rt = 1
             schemes = ("assoc", "shift")
-        else:
-            bq_cands = [8, 16]
-            rt = 8
-            schemes = ("shift",)    # Mosaic cannot lower 'assoc'
         bm_cands = sorted({min(bm, _pow2_bucket(m))
                            for bm in self.BLOCK_M_CANDIDATES})
         scored = []
         for bq in bq_cands:
             for bm in bm_cands:
+                if compiled and (self.block_vregs(bq, bm)
+                                 > self.TPU_MAX_BLOCK_VREGS):
+                    continue
+                rt = self.tpu_row_tile(bq, bm) if compiled else 1
                 for scheme in schemes:
                     us = self.pallas_us(nq, n, m, bq, bm, scheme, rt,
-                                        span=span)
+                                        span=span, lastrow=lastrow)
                     if math.isfinite(us):
                         scored.append(((bq, bm, scheme, rt), us))
         scored.sort(key=lambda t: t[1])
         if not scored:
             raise ValueError(
                 f"no pallas config fits the VMEM budget for nq={nq} "
-                f"n={n} m={m} (span={span})")
+                f"n={n} m={m} (span={span}, lastrow={lastrow})")
         return scored
 
-    def best_pallas(self, nq: int, n: int, m: int,
-                    span: bool = False) -> TunedConfig:
+    @classmethod
+    def tpu_row_tile(cls, block_q: int, block_m: int) -> int:
+        """DP rows the compiled kernel unrolls: 8 while a row's arrays
+        span at most 2 vregs (unrolling overlaps the latency-bound rows),
+        else 2 (the rows are throughput-bound, and the unrolled body and
+        its compile time grow with the rows unrolled)."""
+        return 8 if cls.block_vregs(block_q, block_m) <= 2 else 2
+
+    def best_pallas(self, nq: int, n: int, m: int, span: bool = False,
+                    lastrow: bool = False) -> TunedConfig:
         (bq, bm, scheme, rt), us = self.pallas_candidates(
-            nq, n, m, span=span)[0]
+            nq, n, m, span=span, lastrow=lastrow)[0]
         return TunedConfig(impl="pallas", block_q=bq, block_m=bm,
                            scan_scheme=scheme, row_tile=rt, score_us=us)
 

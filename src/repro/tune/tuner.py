@@ -81,10 +81,12 @@ def _overlay(base: TunedConfig, entry: TunedConfig) -> TunedConfig:
 
 def resolve(nq: int, n: int, m: int, *, backend: Optional[str] = None,
             metric: str = "abs_diff", dtype: str = "int32",
-            mode: str = "model", span: bool = False) -> Resolution:
+            mode: str = "model", span: bool = False,
+            lastrow: bool = False) -> Resolution:
     """The oracle: LRU -> table -> cost model (-> measured search under
     ``mode='measure'``).  Costs are evaluated at the bucket's pow-2
-    shape so every shape in a bucket shares one decision."""
+    shape so every shape in a bucket shares one decision; ``span`` and
+    ``lastrow`` (the kernel variant) size the VMEM working set."""
     backend = canonical_backend(backend)
     key = bucket_key(backend, metric, dtype, nq, n, m)
 
@@ -94,7 +96,7 @@ def resolve(nq: int, n: int, m: int, *, backend: Optional[str] = None,
         nn = _pow2_bucket(max(1, n))
         nm = _pow2_bucket(max(1, m))
         ranked = tuple(model.rank_impls(nb, nn, nm))
-        pal = model.best_pallas(nb, nn, nm, span=span)
+        pal = model.best_pallas(nb, nn, nm, span=span, lastrow=lastrow)
         chunk = model.best_chunk(nb, nn, nm)
         cfg = TunedConfig(
             impl=ranked[0][0], block_q=pal.block_q, block_m=pal.block_m,
@@ -109,13 +111,13 @@ def resolve(nq: int, n: int, m: int, *, backend: Optional[str] = None,
                                   or entry.source != "measured"):
             cfg = measured_search(nb, nn, nm, backend=backend,
                                   metric=metric, dtype=dtype, span=span,
-                                  seed_config=cfg)
+                                  lastrow=lastrow, seed_config=cfg)
             default_table(backend).put(key, cfg)
             source = "measured"
         return Resolution(dataclasses.replace(cfg, source=source),
                           ranked, source)
 
-    return cached((key, span, mode), compute)
+    return cached((key, span, lastrow, mode), compute)
 
 
 # ---------------------------------------------------------------------------
@@ -124,12 +126,14 @@ def resolve(nq: int, n: int, m: int, *, backend: Optional[str] = None,
 
 def tuned_blocks(b: int, m: int, *, n: int, backend: Optional[str] = None,
                  metric: str = "abs_diff", dtype: str = "int32",
-                 mode: str = "model", span: bool = False) -> tuple:
+                 mode: str = "model", span: bool = False,
+                 lastrow: bool = False) -> tuple:
     """Kernel block knobs for ``resolve_blocks``:
     ``(block_q, block_m, scan_scheme, row_tile)``.  'measure' downgrades
     to the table (see module doc — this is called at trace time)."""
     res = resolve(b, n, m, backend=backend, metric=metric, dtype=dtype,
-                  mode="model" if mode == "measure" else mode, span=span)
+                  mode="model" if mode == "measure" else mode, span=span,
+                  lastrow=lastrow)
     c = res.config
     return c.block_q, c.block_m, c.scan_scheme, c.row_tile
 
@@ -198,7 +202,7 @@ def _time_median_us(fn, reps: int = MEASURE_REPS) -> float:
 
 def measured_search(nq: int, n: int, m: int, *, backend: str,
                     metric: str = "abs_diff", dtype: str = "int32",
-                    span: bool = False,
+                    span: bool = False, lastrow: bool = False,
                     seed_config: Optional[TunedConfig] = None,
                     reps: int = MEASURE_REPS, top: int = 3) -> TunedConfig:
     """Refine the model's top candidates on the actual device.
@@ -240,7 +244,8 @@ def measured_search(nq: int, n: int, m: int, *, backend: str,
     if cells <= MEASURE_CAP_CELLS:
         from repro.kernels.sdtw import sdtw_pallas
         cands = [c for c, _ in
-                 model.pallas_candidates(nq, n, m, span=span)[:top]]
+                 model.pallas_candidates(nq, n, m, span=span,
+                                         lastrow=lastrow)[:top]]
         timed = []
         for (cbq, cbm, cscheme, crt) in cands:
             us = _time_median_us(functools.partial(

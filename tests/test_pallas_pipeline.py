@@ -9,6 +9,7 @@ single-compile guarantee (the ragged-tail recompile bugfix), the in-kernel
 last-row capture against the rowscan candidate row, and the scan-scheme /
 row-tile / block-shape invariances of the optimized kernel interior.
 """
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -229,6 +230,24 @@ def test_scheme_row_tile_invariance(scheme, row_tile, rng):
     np.testing.assert_array_equal(np.asarray(d), np.asarray(d2))
     np.testing.assert_array_equal(np.asarray(s), np.asarray(s2))
     np.testing.assert_array_equal(np.asarray(e), np.asarray(e2))
+
+
+@pytest.mark.parametrize("nq", [5, 70])
+def test_tall_block_bitwise(nq, rng):
+    """A tall (64-query) block gives the int32 distances and spans of the
+    (8, ...) block, bitwise, also when the batch leaves the block (or its
+    second block) mostly padding."""
+    n, m = 20, 300
+    q = jnp.asarray(rng.integers(-60, 60, (nq, n)).astype(np.int32))
+    r = jnp.asarray(rng.integers(-60, 60, (m,)).astype(np.int32))
+    qlens = jnp.asarray(rng.integers(n // 2, n + 1, (nq,)).astype(np.int32))
+    kw = dict(block_m=128, scan_scheme="shift", row_tile=2)
+    for extra in ({}, {"return_spans": True}):
+        small = sdtw_pallas(q, r, qlens, block_q=8, **kw, **extra)
+        tall = sdtw_pallas(q, r, qlens, block_q=64, **kw, **extra)
+        for a, b in zip(jax.tree_util.tree_leaves(small),
+                        jax.tree_util.tree_leaves(tall)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_resolve_blocks_contract():

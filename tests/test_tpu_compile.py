@@ -5,7 +5,9 @@ mode accepts — dynamic slices, lane slices not provably 128-aligned,
 zero-width vectors — so interpret-mode tests alone cannot show that the
 kernel runs on the chip. These tests compile it for a *described* v5e
 topology (``jax.experimental.topologies``) at the paper's Table V query
-lengths, with the TPU default block and with the autotuner's TPU pick.
+lengths, with the TPU default block and with the autotuner's TPU pick
+for a batch of 16 and for the batch sizes of the two batch cells (128
+and 16,384 queries), where the pick is a tall block.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process may load the TPU library at a time, and every test
@@ -33,11 +35,25 @@ from oracle import sdtw_span
 #: Table V reference length per query length (Seismology, ECG, Power).
 TABLE_V = {64: 1_727_990, 512: 1_800_000, 1536: 1_754_985}
 BATCH = 16
+#: The block each case compiles: the kernel's TPU default, or the tuner's
+#: pick for a batch of that many queries.
+BLOCKS = {"default": None, "tuned": BATCH, "tuned-b128": 128,
+          "tuned-b16384": 16384}
 VARIANTS = {
     "plain": {},
     "spans": {"return_spans": True},
     "lastrow": {"return_lastrow": True, "track_start": True},
 }
+#: The last-row capture returns a (batch, M) row, so a large batch gets a
+#: slice of the reference (as the search and stream paths hand the kernel
+#: slices): at most this many cells per returned row array.
+LASTROW_CELLS = 1 << 28
+
+
+def _ref_len(batch, n, variant):
+    if variant == "lastrow":
+        return min(TABLE_V[n], LASTROW_CELLS // batch)
+    return TABLE_V[n]
 
 
 @pytest.fixture(scope="module")
@@ -57,10 +73,10 @@ def one_chip(topo):
 
 @pytest.fixture(scope="module")
 def tpu_picks(topo):
-    """The autotuner's TPU block pick per (N, span), priced with the cost
-    constants of the described chip's ``device_kind``. The tuner's process
-    caches are emptied before and after, so no other test sees TPU
-    decisions made on a CPU host."""
+    """The autotuner's TPU block pick per (batch, N, variant), priced with
+    the cost constants of the described chip's ``device_kind``. The
+    tuner's process caches are emptied before and after, so no other test
+    sees TPU decisions made on a CPU host."""
     kind = topo.devices[0].device_kind
     picks = {}
     with pytest.MonkeyPatch.context() as mp:
@@ -68,17 +84,20 @@ def tpu_picks(topo):
         mp.setattr(cost, "_MODELS", {})
         clear_tuning_cache()
         try:
-            for n, m in TABLE_V.items():
-                for span in (False, True):
-                    picks[n, span] = tuned_blocks(BATCH, m, n=n,
-                                                  backend="tpu", span=span)
+            for b in set(BLOCKS.values()) - {None}:
+                for n in TABLE_V:
+                    for variant in VARIANTS:
+                        picks[b, n, variant] = tuned_blocks(
+                            b, _ref_len(b, n, variant), n=n, backend="tpu",
+                            span=variant != "plain",
+                            lastrow=variant == "lastrow")
         finally:
             clear_tuning_cache()
     return picks
 
 
-def _compile(one_chip, n, m, **kw):
-    q = jax.ShapeDtypeStruct((BATCH, n), jnp.int32, sharding=one_chip)
+def _compile(one_chip, n, m, batch=BATCH, **kw):
+    q = jax.ShapeDtypeStruct((batch, n), jnp.int32, sharding=one_chip)
     r = jax.ShapeDtypeStruct((m,), jnp.int32, sharding=one_chip)
     fn = jax.jit(lambda q, r: sdtw_pallas(q, r, interpret=False, tune="off",
                                           **kw))
@@ -87,15 +106,19 @@ def _compile(one_chip, n, m, **kw):
 
 @pytest.mark.parametrize("variant", sorted(VARIANTS))
 @pytest.mark.parametrize("n", sorted(TABLE_V))
-@pytest.mark.parametrize("block", ["default", "tuned"])
+@pytest.mark.parametrize("block", sorted(BLOCKS))
 def test_kernel_compiles_for_v5e(one_chip, tpu_picks, block, n, variant):
     kw = dict(VARIANTS[variant])
-    if block == "tuned":
-        bq, bm, scheme, rt = tpu_picks[n, variant != "plain"]
+    batch = BLOCKS[block] or BATCH
+    if BLOCKS[block] is not None:
+        bq, bm, scheme, rt = tpu_picks[batch, n, variant]
         assert scheme == "shift", "the TPU tuner offered a scheme Mosaic " \
                                   "cannot lower"
+        if batch > BATCH:
+            assert bq >= 32, "a batch this large should get a tall block"
         kw.update(block_q=bq, block_m=bm, scan_scheme=scheme, row_tile=rt)
-    compiled = _compile(one_chip, n, TABLE_V[n], **kw)
+    compiled = _compile(one_chip, n, _ref_len(batch, n, variant), batch,
+                        **kw)
     # A Mosaic kernel, not an interpreted (XLA-lowered) grid.
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis() is not None

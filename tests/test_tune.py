@@ -303,6 +303,22 @@ def test_explain_decision_contents(rng):
         sdtw([np.asarray(q)[0]], r, explain=True)
 
 
+def test_explain_reports_the_streamed_launch_blocks(monkeypatch, rng):
+    """Past ``PALLAS_FUSED_MAX`` the kernel launches one ``chunk`` slice
+    at a time, and ``explain`` reports the blocks of that launch."""
+    from repro.core import engine
+    monkeypatch.setattr(engine, "PALLAS_FUSED_MAX", 256)
+    q, r = _mk(rng, nq=3, n=16, m=700)
+    out, dec = sdtw(q, r, impl="pallas", chunk=128, explain=True)
+    want = resolve_blocks(3, 128, None, None, None, None, True, n=16,
+                          tune="model")
+    got = tuple(dec.config[k] for k in ("block_q", "block_m",
+                                        "scan_scheme", "row_tile"))
+    assert got == want
+    np.testing.assert_array_equal(np.asarray(out),
+                                  np.asarray(sdtw(q, r, tune="off")))
+
+
 def test_explain_rejected_by_serve():
     from repro.core.request import SdtwRequest
     from repro.serve import Router
@@ -332,3 +348,75 @@ def test_router_warmup_pretunes(rng):
         np.testing.assert_array_equal(
             np.asarray(fut.result()),
             np.asarray(sdtw(q, r, tune="off")))
+
+
+# ---------------------------------------------------------------------------
+# 4. The compiled kernel's price: tall blocks for batches
+# ---------------------------------------------------------------------------
+
+#: Table V query length -> reference length (Seismology, Human, ECG, Power).
+TABLE_V = {64: 1_727_990, 120: 7_997, 512: 1_800_000, 1536: 1_754_985}
+
+
+def _tpu_model():
+    from repro.core.platforms import TPU_V5E_BACKEND
+    return KernelCostModel(TPU_V5E_BACKEND)
+
+
+def _bucket(nq, n):
+    """The pow-2 bucket the oracle prices a (nq, n, Table V M) call at."""
+    from repro.tune.cost import _pow2_bucket
+    return _pow2_bucket(nq), _pow2_bucket(n), _pow2_bucket(TABLE_V[n])
+
+
+@pytest.mark.parametrize("span", [False, True])
+@pytest.mark.parametrize("nq", [64, 128, 16384])
+@pytest.mark.parametrize("n", sorted(TABLE_V))
+def test_tpu_batches_get_tall_blocks(n, nq, span):
+    cfg = _tpu_model().best_pallas(*_bucket(nq, n), span=span)
+    assert cfg.block_q >= 32, cfg
+    assert cfg.scan_scheme == "shift"
+
+
+@pytest.mark.parametrize("span", [False, True])
+@pytest.mark.parametrize("nq", [1, 3, 8])
+@pytest.mark.parametrize("n", sorted(TABLE_V))
+def test_tpu_small_batches_keep_the_sublane_block(n, nq, span):
+    for (bq, _, _, _), _ in _tpu_model().pallas_candidates(
+            *_bucket(nq, n), span=span):
+        assert bq == 8
+
+
+@pytest.mark.parametrize("lastrow", [False, True])
+@pytest.mark.parametrize("span", [False, True])
+@pytest.mark.parametrize("n", sorted(TABLE_V))
+def test_tpu_candidates_fit_vmem(n, span, lastrow):
+    model = _tpu_model()
+    budget = model.backend.vmem_budget_words
+    for nq in (1, 128, 16384):
+        cands = model.pallas_candidates(*_bucket(nq, n), span=span,
+                                        lastrow=lastrow)
+        assert cands
+        for (bq, bm, _, _), _ in cands:
+            assert model.vmem_words(bq, bm, n, span, lastrow) <= budget
+    # the last-row output block is counted
+    assert model.vmem_words(128, 512, n, span, True) > \
+        model.vmem_words(128, 512, n, span, False)
+
+
+@pytest.mark.parametrize("shape,span,top", [
+    ((8, 64, 4096), False, ((4, 512, "assoc", 1), 358787.104)),
+    ((8, 64, 4096), True, ((4, 512, "assoc", 1), 358787.104)),
+    ((4, 32, 16384), False, ((4, 512, "assoc", 1), 361189.152)),
+    ((4, 32, 1024), False, ((4, 512, "assoc", 1), 23043.072)),
+    ((2, 16, 256), False, ((2, 256, "assoc", 1), 2237.6416)),
+    ((3, 24, 700), False, ((3, 256, "assoc", 1), 10855.6743237184)),
+    ((1, 120, 8192), False, ((1, 2048, "assoc", 1), 167922.8544)),
+])
+def test_interpret_price_unchanged(shape, span, top):
+    """The interpret-mode price is the one ``repro.tune.validate`` gates
+    on: its picks and scores are pinned."""
+    (cfg, us), = get_cost_model("interpret").pallas_candidates(
+        *shape, span=span)[:1]
+    assert cfg == top[0]
+    assert us == pytest.approx(top[1], rel=1e-12)
