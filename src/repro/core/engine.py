@@ -440,7 +440,8 @@ def stream(queries, *, qlens=None, metric: str = "abs_diff",
            return_positions: bool = False, excl_lo=None, excl_hi=None,
            prune: bool = False, span_cap: Optional[int] = None,
            alert_threshold=None, on_alert=None, cache=None, ref_key=None,
-           block_q: Optional[int] = None, block_m: Optional[int] = None):
+           block_q: Optional[int] = None, block_m: Optional[int] = None,
+           streams: int = 1):
     """Open an online monitoring session: the streaming front door.
 
     Where ``sdtw()`` answers one offline query batch against a
@@ -462,7 +463,10 @@ def stream(queries, *, qlens=None, metric: str = "abs_diff",
     only for per-query exclusion zones, which the kernel does not
     support) and the rowscan tile loop everywhere else. ``chunk`` is the
     internal DP tile size (compile granularity) — feed granularity is
-    independent of it.
+    independent of it. ``streams=S`` opens one session over S aligned
+    streams that share the queries: ``feed`` takes ``(S, samples)`` and
+    one launch per tile advances them all (not with ``mesh=``,
+    ``prune=True`` or exclusion bands).
     """
     return StreamRequest(
         queries=queries, qlens=qlens, metric=metric, impl=impl,
@@ -472,7 +476,8 @@ def stream(queries, *, qlens=None, metric: str = "abs_diff",
         return_positions=return_positions, excl_lo=excl_lo,
         excl_hi=excl_hi, prune=prune, span_cap=span_cap,
         alert_threshold=alert_threshold, on_alert=on_alert, cache=cache,
-        ref_key=ref_key, block_q=block_q, block_m=block_m).open()
+        ref_key=ref_key, block_q=block_q, block_m=block_m,
+        streams=streams).open()
 
 
 def align(queries, reference, qlens=None, *, metric: str = "abs_diff",

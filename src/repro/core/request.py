@@ -418,6 +418,7 @@ class StreamRequest:
     ref_key: Any = None
     block_q: Optional[int] = None
     block_m: Optional[int] = None
+    streams: int = 1
 
     @classmethod
     def from_kwargs(cls, **kwargs) -> "StreamRequest":
@@ -446,6 +447,10 @@ class StreamRequest:
                              "pipeline; pass mesh=/mesh_shape= (or "
                              "impl='sharded') or drop n_micro=")
         if mesh is not None or self.impl == "sharded":
+            if self.streams != 1:
+                raise ValueError("streams= stacks streams on one device; a "
+                                 "sharded session splits one stream over "
+                                 "a mesh (drop mesh= or streams=)")
             if self.prune:
                 raise ValueError("mesh= streams every chunk; the LB cascade "
                                  "is single-process (drop prune=True)")
@@ -493,6 +498,17 @@ class StreamRequest:
                              "exclusion zones; use impl='rowscan'")
         if self.chunk is not None and int(self.chunk) < 1:
             raise ValueError(f"chunk must be >= 1, got {int(self.chunk)}")
+        if not isinstance(self.streams, int) or self.streams < 1:
+            raise ValueError(f"streams must be a positive int, got "
+                             f"{self.streams!r}")
+        if self.streams > 1 and self.prune:
+            raise ValueError("prune=True skips tiles per stream; a "
+                             "many-stream session advances every stream "
+                             "every tile (drop prune= or streams=)")
+        if self.streams > 1 and self.excl_lo is not None:
+            raise ValueError("exclusion bands are not supported in a "
+                             "many-stream session (drop excl_lo/excl_hi "
+                             "or streams=)")
         return self
 
     # ------------------------------------------------------------------
@@ -532,4 +548,5 @@ class StreamRequest:
             excl_lo=self.excl_lo, excl_hi=self.excl_hi, prune=self.prune,
             span_cap=self.span_cap, alert_threshold=self.alert_threshold,
             on_alert=self.on_alert, cache=self.cache, ref_key=self.ref_key,
-            block_q=self.block_q, block_m=self.block_m)
+            block_q=self.block_q, block_m=self.block_m,
+            streams=self.streams)

@@ -462,20 +462,28 @@ def sdtw_chunk_batch(queries, ref_chunk, qlens, carry, j0, m_total,
     ``carry`` is ``(bcol (nq, N), best (nq,))`` or, with the start lane,
     ``(bcol, bstart, best)`` — the lane is tracked iff it is present.
     ``clen`` (traced) is the chunk's true column count — see
-    ``sdtw_rowscan_chunk``."""
+    ``sdtw_rowscan_chunk``. A 2-D ``ref_chunk`` (nq, C) gives each query
+    its own reference row over the same columns."""
     if len(carry) == 3:
         bcol, bstart, best = carry
-        return jax.vmap(
-            lambda q, ql, bc, bs, be, lo, hi: sdtw_rowscan_chunk(
-                q, ref_chunk, bc, be, ql, j0, m_total, metric, lo, hi,
-                bstart=bs, clen=clen)
-        )(queries, qlens, bcol, bstart, best, excl_lo, excl_hi)
+        return _map_rows(
+            lambda q, r, ql, bc, bs, be, lo, hi: sdtw_rowscan_chunk(
+                q, r, bc, be, ql, j0, m_total, metric, lo, hi,
+                bstart=bs, clen=clen),
+            queries, ref_chunk, qlens, bcol, bstart, best, excl_lo, excl_hi)
     bcol, best = carry
-    return jax.vmap(
-        lambda q, ql, bc, be, lo, hi: sdtw_rowscan_chunk(
-            q, ref_chunk, bc, be, ql, j0, m_total, metric, lo, hi,
-            clen=clen)
-    )(queries, qlens, bcol, best, excl_lo, excl_hi)
+    return _map_rows(
+        lambda q, r, ql, bc, be, lo, hi: sdtw_rowscan_chunk(
+            q, r, bc, be, ql, j0, m_total, metric, lo, hi, clen=clen),
+        queries, ref_chunk, qlens, bcol, best, excl_lo, excl_hi)
+
+
+def _map_rows(fn, queries, ref_chunk, *rest):
+    """``fn(query, ref, *rest)`` mapped over the query rows; the reference
+    is mapped too when it is 2-D (one row per query), else shared."""
+    ref_axis = 0 if ref_chunk.ndim == 2 else None
+    return jax.vmap(fn, in_axes=(0, ref_axis) + (0,) * len(rest))(
+        queries, ref_chunk, *rest)
 
 
 def sdtw_chunk_batch_topk(queries, ref_chunk, qlens, carry, j0, m_total,
@@ -502,13 +510,13 @@ def sdtw_chunk_batch_topk(queries, ref_chunk, qlens, carry, j0, m_total,
     candidate row (and, when tracked, its start lane) to the output —
     the streaming monitor's threshold-alert feed.
     """
-    pos = j0 + jnp.arange(ref_chunk.shape[0], dtype=jnp.int32)
+    pos = j0 + jnp.arange(ref_chunk.shape[-1], dtype=jnp.int32)
     if track_start:
         bcol, bstart, best, top_d, top_p, top_s = carry
 
-        def one(q, ql, bc, bs, be, lo, hi, hd, hp, hs, ez):
+        def one(q, r, ql, bc, bs, be, lo, hi, hd, hp, hs, ez):
             nbc, nbs, nbe, lrow, lstart = sdtw_rowscan_chunk(
-                q, ref_chunk, bc, be, ql, j0, m_total, metric, lo, hi,
+                q, r, bc, be, ql, j0, m_total, metric, lo, hi,
                 return_lastrow=True, bstart=bs, clen=clen)
             nd, np_, ns = topk_merge(hd, hp, hs, lrow, pos, lstart, k, ez,
                                      excl_span)
@@ -516,23 +524,23 @@ def sdtw_chunk_batch_topk(queries, ref_chunk, qlens, carry, j0, m_total,
                 return nbc, nbs, nbe, nd, np_, ns, lrow, lstart
             return nbc, nbs, nbe, nd, np_, ns
 
-        return jax.vmap(one)(queries, qlens, bcol, bstart, best, excl_lo,
-                             excl_hi, top_d, top_p, top_s, excl_zone)
+        return _map_rows(one, queries, ref_chunk, qlens, bcol, bstart, best,
+                         excl_lo, excl_hi, top_d, top_p, top_s, excl_zone)
     assert not excl_span, "span-overlap suppression needs the start lane"
     bcol, best, top_d, top_p, top_s = carry
     no_start = jnp.full_like(pos, -1)
 
-    def one(q, ql, bc, be, lo, hi, hd, hp, hs, ez):
+    def one(q, r, ql, bc, be, lo, hi, hd, hp, hs, ez):
         nbc, nbe, lrow = sdtw_rowscan_chunk(
-            q, ref_chunk, bc, be, ql, j0, m_total, metric, lo, hi,
+            q, r, bc, be, ql, j0, m_total, metric, lo, hi,
             return_lastrow=True, clen=clen)
         nd, np_, ns = topk_merge(hd, hp, hs, lrow, pos, no_start, k, ez)
         if return_lastrow:
             return nbc, nbe, nd, np_, ns, lrow
         return nbc, nbe, nd, np_, ns
 
-    return jax.vmap(one)(queries, qlens, bcol, best, excl_lo, excl_hi,
-                         top_d, top_p, top_s, excl_zone)
+    return _map_rows(one, queries, ref_chunk, qlens, bcol, best, excl_lo,
+                     excl_hi, top_d, top_p, top_s, excl_zone)
 
 
 def topk_fold_lastrow(heap, lastrow, lstarts, j0, k: int, excl_zone,

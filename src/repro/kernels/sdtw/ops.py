@@ -79,13 +79,16 @@ def interpret_mode(backend: str | None = None) -> bool:
 def resolve_blocks(b: int, m: int, block_q, block_m, scan_scheme, row_tile,
                    interpret: bool, *, n=None, metric: str = "abs_diff",
                    dtype: str = "int32", tune: str = "off",
-                   span: bool = False, lastrow: bool = False):
+                   span: bool = False, lastrow: bool = False,
+                   rowref: bool = False):
     """Fill in the auto (None) kernel tuning knobs for this call shape.
 
     Returns ``(block_q, block_m, scan_scheme, row_tile)``. With
     ``tune != 'off'`` (and ``n`` known) the unset knobs come from the
-    ``repro.tune`` oracle — table hit, else cost-model pick; explicit
-    (non-None) knobs always win. Otherwise the legacy heuristics apply:
+    ``repro.tune`` oracle — table hit, else cost-model pick, priced for
+    the kernel variant (``span``, ``lastrow``, and ``rowref``: one
+    reference row per query row); explicit (non-None) knobs always win.
+    Otherwise the legacy heuristics apply:
     interpret mode has no sublane/lane alignment to respect, so the query
     block fits the batch exactly (padding queries to a multiple of 8
     would be pure wasted compute) and the reference tile grows to cover
@@ -99,7 +102,7 @@ def resolve_blocks(b: int, m: int, block_q, block_m, scan_scheme, row_tile,
         tq, tm, ts, tr = tuned_blocks(
             b, m, n=int(n), backend="tpu" if not interpret else "interpret",
             metric=metric, dtype=dtype, mode=tune, span=span,
-            lastrow=lastrow)
+            lastrow=lastrow, rowref=rowref)
         block_q = tq if block_q is None else block_q
         block_m = tm if block_m is None else block_m
         scan_scheme = ts if scan_scheme is None else scan_scheme
@@ -172,6 +175,14 @@ def sdtw_pallas(queries, reference, qlens=None, metric: str = "abs_diff",
                 tune: str = "off"):
     """Batched sDTW on TPU via Pallas. queries (B, N), reference (M,) → (B,).
 
+    A 2-D reference ``(B, M)`` gives every query row its own reference
+    row (the same columns, so ``ref_offset``/``ref_len``/``ref_lead`` are
+    shared): rows of independent streams then share one launch block.
+    The kernel reads a ``(block_q, block_m)`` reference tile instead of
+    the broadcast ``(1, block_m)`` one; a 1-D reference compiles the
+    shared-reference kernel unchanged (the choice is made at trace time
+    from the reference's rank).
+
     VMEM working set per grid cell ≈
     ``block_q · (3·block_m + 3·N)`` accumulator words plain,
     ``block_q · (6·block_m + 5·N)`` in span mode (the start lanes are
@@ -182,9 +193,10 @@ def sdtw_pallas(queries, reference, qlens=None, metric: str = "abs_diff",
     block-wide row vectors live (prev / captured-last-row / scan
     temporaries, plus the start lanes). ``return_lastrow`` adds a
     double-buffered ``block_q · block_m`` output block (+ its int32 start
-    lane in span mode). Block shapes must be chosen so this fits (~16 MB
-    VMEM on v5e); the TPU defaults handle N ≤ 48K (plain) / N ≤ 24K
-    (spans) comfortably.
+    lane in span mode), and a per-row reference a double-buffered
+    ``block_q · block_m`` input tile. Block shapes must be chosen so this
+    fits (~16 MB VMEM on v5e); the TPU defaults handle N ≤ 48K (plain) /
+    N ≤ 24K (spans) comfortably.
     ``repro.tune.KernelCostModel.vmem_words`` prices candidates with this
     same formula, so any config the autotuner proposes fits by construction.
 
@@ -228,14 +240,19 @@ def sdtw_pallas(queries, reference, qlens=None, metric: str = "abs_diff",
     if interpret is None:
         interpret = interpret_mode()
     b, n = queries.shape
-    m = reference.shape[0]
+    m = reference.shape[-1]
+    rowref = reference.ndim == 2
+    if rowref and reference.shape[0] != b:
+        raise ValueError(f"a per-row reference needs one row per query: "
+                         f"got {reference.shape[0]} rows for {b} queries")
     acc = accum_dtype(jnp.result_type(queries, reference))
     BIG = big(acc)
     block_q, block_m, scan_scheme, row_tile = resolve_blocks(
         b, m, block_q, block_m, scan_scheme, row_tile, interpret,
         n=n, metric=metric,
         dtype=str(jnp.result_type(queries, reference)), tune=tune,
-        span=return_spans or track_start, lastrow=return_lastrow)
+        span=return_spans or track_start, lastrow=return_lastrow,
+        rowref=rowref)
     if scan_scheme == "assoc" and not interpret:
         raise ValueError(
             "scan_scheme='assoc' does not compile for the TPU (Mosaic "
@@ -278,7 +295,11 @@ def sdtw_pallas(queries, reference, qlens=None, metric: str = "abs_diff",
     npad = _ceil_to(n, LANES)
 
     q_pad = jnp.zeros((bp, npad), queries.dtype).at[:b, :n].set(queries)
-    r_pad = jnp.zeros((1, mp), reference.dtype).at[0, :m].set(reference)
+    if rowref:
+        r_pad = jnp.zeros((bp, mp), reference.dtype).at[:b, :m].set(
+            reference)
+    else:
+        r_pad = jnp.zeros((1, mp), reference.dtype).at[0, :m].set(reference)
     qlen_pad = jnp.ones((bp, 1), jnp.int32).at[:b, 0].set(qlens)
     rlen = jnp.full((1, 1), m if ref_len is None else ref_len, jnp.int32)
     lead = jnp.full((1, 1), ref_lead, jnp.int32)
@@ -293,9 +314,10 @@ def sdtw_pallas(queries, reference, qlens=None, metric: str = "abs_diff",
 
     col_spec = pl.BlockSpec((block_q, npad), lambda qb, t: (qb, 0))
     scalar_spec = pl.BlockSpec((block_q, 1), lambda qb, t: (qb, 0))
-    tile_spec = pl.BlockSpec((1, block_m), lambda qb, t: (0, t))
     one_spec = pl.BlockSpec((1, 1), lambda qb, t: (0, 0))
     row_spec = pl.BlockSpec((block_q, block_m), lambda qb, t: (qb, t))
+    tile_spec = (row_spec if rowref
+                 else pl.BlockSpec((1, block_m), lambda qb, t: (0, t)))
 
     inputs = [q_pad, r_pad, qlen_pad, rlen, lead, off, bcol_pad]
     in_specs = [col_spec, tile_spec, scalar_spec, one_spec, one_spec,

@@ -159,7 +159,9 @@ def _sdtw_kernel(metric, n, block_m, track, want_lastrow, scheme, row_tile,
 
     q_ref:       (block_q, Np)  queries (VMEM), N padded to Np, a multiple
                                 of ``LANES`` — read one window at a time
-    r_ref:       (1, block_m)   reference tile (VMEM)
+    r_ref:       (1, block_m)   reference tile (VMEM), broadcast over the
+                                query rows; (block_q, block_m) when each
+                                query row has its own reference row
     qlen_ref:    (block_q, 1)   true query lengths
     rlen_ref:    (1, 1)         true reference length (columns >= rlen are
                                 masked; the carry exits at column rlen-1)
@@ -228,7 +230,7 @@ def _sdtw_kernel(metric, n, block_m, track, want_lastrow, scheme, row_tile,
     scan = _SCAN_SCHEMES[scheme]
 
     # Loop invariants, read/computed once per tile.
-    r = r_ref[...].astype(acc)                       # (1, bm)
+    r = r_ref[...].astype(acc)                       # (1 | bq, bm)
     qlen = qlen_ref[...].astype(jnp.int32)           # (bq, 1)
     rlen = rlen_ref[0, 0]
     lead = lead_ref[0, 0]

@@ -46,6 +46,17 @@ Mechanics that make streaming practical:
     ``AlertEvent`` (appended to ``session.alerts`` and passed to the
     ``on_alert`` callback) — feed anomaly templates as queries and the
     session becomes an online anomaly detector.
+  * **Many streams, one launch per tile.** ``streams=S`` opens one
+    session over S independent streams (patients, sensors) that share
+    the query set and arrive aligned: ``feed`` takes ``(S, samples)``.
+    The query rows are stacked stream-major (S · nq rows, each stream's
+    carries and heaps its own rows), each stream's tile is repeated for
+    its queries as a per-row reference, and one jitted step per tile
+    advances every stream through one kernel launch. Alerts are reduced
+    on the device to one (hits, distance, end, start) per row and
+    fetched once per tile; ``results()`` gains a leading stream axis and
+    every ``AlertEvent`` names its ``stream``. Each stream's answers
+    equal those of its own one-stream session, bitwise.
   * **Fault tolerance.** ``session.snapshot()`` returns a flat dict of
     numpy arrays (``np.savez``-able as-is); ``StreamSession.restore``
     rebuilds a session that continues bit-for-bit where the original
@@ -83,9 +94,10 @@ from typing import Callable, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import engine as engine_mod
-from repro.core.distances import accum_dtype, big
+from repro.core.distances import INT_FAR, accum_dtype, big
 from repro.core.request import StreamRequest
 from repro.core.sdtw import (default_excl_zone, sdtw_carry_init,
                              sdtw_chunk_batch, sdtw_chunk_batch_topk,
@@ -98,15 +110,16 @@ from repro.search.search import DEFAULT_SPAN_FACTOR, _pruned_chunk_step
 #: Default DP tile size — the engine's streaming default.
 DEFAULT_STREAM_CHUNK = engine_mod.DEFAULT_CHUNK
 
-_SNAP_VERSION = 1
+#: Version 2 added ``streams``; a version-1 snapshot is a one-stream one.
+_SNAP_VERSION = 2
 
 
 @dataclasses.dataclass(frozen=True)
 class AlertEvent:
-    """One threshold crossing: query ``query`` matched the stream at cost
-    ``distance`` ending at global sample ``end`` (span start ``start``;
-    -1 when the session does not track starts). ``hits`` counts every
-    sub-threshold end column inside the triggering tile
+    """One threshold crossing: query ``query`` matched stream ``stream``
+    at cost ``distance`` ending at global sample ``end`` (span start
+    ``start``; -1 when the session does not track starts). ``hits``
+    counts every sub-threshold end column inside the triggering tile
     ``[tile_start, tile_end)``; the reported (distance, end) is the best
     (leftmost on ties)."""
     query: int
@@ -116,6 +129,7 @@ class AlertEvent:
     tile_start: int
     tile_end: int
     hits: int
+    stream: int = 0
 
 
 @dataclasses.dataclass
@@ -123,7 +137,8 @@ class StreamResult:
     """Streamed match state after ``samples`` reference samples.
 
     ``distances`` is (nq,) — or (nq, k) in top-K mode, best first,
-    BIG/-1-padded like ``repro.core.topk``. ``positions``/``starts`` are
+    BIG/-1-padded like ``repro.core.topk`` — with a leading stream axis
+    in a many-stream session. ``positions``/``starts`` are
     present when the session tracks ends/spans. Tile counters are
     per-*tile* across the whole (possibly multi-bucket) batch —
     ``tiles_pruned + tiles_processed == tiles_total`` always, unlike
@@ -153,28 +168,19 @@ class StreamResult:
 
 @dataclasses.dataclass
 class _Bucket:
-    """One padded query bucket and its carry through the stream."""
+    """One padded query bucket and its carry through the stream. Its
+    rows are the bucket's ``nb`` queries once per stream, stream-major."""
     idxs: List[int]
-    queries: jnp.ndarray        # (nb, blen)
-    qlens: jnp.ndarray          # (nb,)
-    lo: jnp.ndarray             # (nb,) banned-range lower bounds
+    queries: jnp.ndarray        # (streams * nb, blen)
+    qlens: jnp.ndarray          # (streams * nb,)
+    lo: jnp.ndarray             # (streams * nb,) banned-range lower bounds
     hi: jnp.ndarray
-    zone: jnp.ndarray           # (nb,) top-K suppression radii
+    zone: jnp.ndarray           # (streams * nb,) top-K suppression radii
     carry: tuple                # chunk carry (+ heap in match mode)
     halo: int = 0               # pruned mode: left-context tiles
     thr: Optional[np.ndarray] = None  # pruned mode: per-query k-th best
 
 
-@functools.partial(jax.jit, static_argnames=("metric",))
-def _plain_step(queries, tile, qlens, carry, j0, m_total, clen, lo, hi, *,
-                metric):
-    return sdtw_chunk_batch(queries, tile, qlens, carry, j0, m_total,
-                            metric, lo, hi, clen=clen)
-
-
-@functools.partial(jax.jit, static_argnames=("metric", "block_q", "block_m",
-                                             "k", "excl_span", "track",
-                                             "want_lastrow", "with_heap"))
 def _pallas_step(queries, tile, qlens, kcarry, heap, j0, clen, zone, *,
                  metric, block_q, block_m, k, excl_span, track,
                  want_lastrow, with_heap):
@@ -182,12 +188,13 @@ def _pallas_step(queries, tile, qlens, kcarry, heap, j0, clen, zone, *,
     chunk carry and — when the session consumes candidate rows — fold the
     in-kernel last-row capture into the top-K heap with the identical
     per-tile ``topk_merge`` the rowscan path runs, so pallas sessions
-    reproduce the offline chunked heap bitwise (int32)."""
+    reproduce the offline chunked heap bitwise (int32). The launch block
+    comes from the tuner, keyed on the call's shape."""
     from repro.kernels.sdtw import sdtw_pallas
     out = sdtw_pallas(queries, tile, qlens, metric, block_q=block_q,
                       block_m=block_m, carry=kcarry, return_carry=True,
                       ref_offset=j0, ref_len=clen, track_start=track,
-                      return_lastrow=want_lastrow)
+                      return_lastrow=want_lastrow, tune="model")
     if not want_lastrow:
         _, kc = out
         return kc, None, None
@@ -203,6 +210,28 @@ def _pallas_step(queries, tile, qlens, kcarry, heap, j0, clen, zone, *,
     return kc, lrow, lstart
 
 
+def _tile_alerts(lrow, lstart, clen, thr):
+    """Each row's alert for one tile, reduced on the device: ``(hits,
+    distance, column, start)`` — the count of the tile's columns whose
+    candidate cost is at most ``thr``, the least such cost, the leftmost
+    column attaining it, and its span start (-1 untracked). A row with no
+    hit reads ``hits == 0``."""
+    cols = jnp.arange(lrow.shape[1], dtype=jnp.int32)[None, :]
+    hit = (lrow <= thr) & (cols < clen)
+    hits = jnp.sum(hit, axis=1, dtype=jnp.int32)
+    fill = (jnp.iinfo(lrow.dtype).max
+            if jnp.issubdtype(lrow.dtype, jnp.integer) else jnp.inf)
+    dist = jnp.min(jnp.where(hit, lrow, fill), axis=1)
+    at = hit & (lrow == dist[:, None])
+    col = jnp.min(jnp.where(at, cols, lrow.shape[1]), axis=1)
+    if lstart is None:
+        start = jnp.full_like(col, -1)
+    else:
+        start = jnp.min(jnp.where(at & (cols == col[:, None]), lstart,
+                                  INT_FAR), axis=1)
+    return hits, dist, col, start
+
+
 @functools.partial(jax.jit, static_argnames=("metric", "k", "excl_span",
                                              "track", "lastrow"))
 def _heap_step(queries, tile, qlens, carry, j0, m_total, clen, lo, hi, zone,
@@ -215,6 +244,39 @@ def _heap_step(queries, tile, qlens, carry, j0, m_total, clen, lo, hi, zone,
     if track:
         return out[:6], out[6], out[7]
     return out[:5], out[5], None
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "impl", "metric", "block_q", "block_m", "k", "excl_span", "track",
+    "want_lastrow", "with_heap", "alert"))
+def _exact_step(queries, tile, qlens, carry, j0, clen, lo, hi, zone, thr, *,
+                impl, metric, block_q, block_m, k, excl_span, track,
+                want_lastrow, with_heap, alert):
+    """One exact-mode tile for one bucket, as one jitted step: advance the
+    carry and, with ``alert``, reduce the tile's candidate rows to each
+    row's alert (``_tile_alerts``). A 2-D ``tile`` (streams, C) is one
+    tile per stream: each is repeated for its stream's query rows (which
+    are stream-major) as a per-row reference."""
+    if tile.ndim == 2:
+        tile = jnp.repeat(tile, queries.shape[0] // tile.shape[0], axis=0)
+    if impl == "pallas":
+        kc = carry[:-3] if with_heap else carry
+        heap = carry[-3:] if with_heap else None
+        new, lrow, lstart = _pallas_step(
+            queries, tile, qlens, kc, heap, j0, clen, zone, metric=metric,
+            block_q=block_q, block_m=block_m, k=k, excl_span=excl_span,
+            track=track, want_lastrow=want_lastrow, with_heap=with_heap)
+    elif with_heap:
+        new, lrow, lstart = _heap_step(
+            queries, tile, qlens, carry, j0, j0 + clen, clen, lo, hi, zone,
+            metric=metric, k=k, excl_span=excl_span, track=track,
+            lastrow=want_lastrow)
+    else:
+        new = sdtw_chunk_batch(queries, tile, qlens, carry, j0, j0 + clen,
+                               metric, lo, hi, clen=clen)
+    if not alert:
+        return new, None
+    return new, _tile_alerts(lrow, lstart, clen, thr)
 
 
 class StreamSession:
@@ -233,7 +295,7 @@ class StreamSession:
                  on_alert: Optional[Callable[[AlertEvent], None]] = None,
                  cache: Optional[cache_mod.EnvelopeCache] = None,
                  ref_key=None, block_q: Optional[int] = None,
-                 block_m: Optional[int] = None):
+                 block_m: Optional[int] = None, streams: int = 1):
         if impl not in ("rowscan", "pallas"):
             raise ValueError(f"impl must be 'rowscan' or 'pallas' for a "
                              f"stream session, got {impl!r}")
@@ -248,7 +310,7 @@ class StreamSession:
             excl_hi=excl_hi, prune=prune, span_cap=span_cap,
             alert_threshold=alert_threshold, on_alert=on_alert,
             cache=cache, ref_key=ref_key, block_q=block_q,
-            block_m=block_m).validate_session()
+            block_m=block_m, streams=streams).validate_session()
 
         self.metric = metric
         self.impl = impl
@@ -265,6 +327,7 @@ class StreamSession:
         self.cache = cache_mod.DEFAULT_CACHE if cache is None else cache
         self.block_q = block_q
         self.block_m = block_m
+        self.streams = int(streams)
         self.alerts: List[AlertEvent] = []
 
         self._derive_modes()
@@ -303,6 +366,12 @@ class StreamSession:
             zone_all = np.broadcast_to(
                 np.asarray(excl_zone, np.int32), (self._nq,))
 
+        def per_stream(a):
+            """A bucket's per-query array, once per stream, stream-major."""
+            a = np.asarray(a)
+            return jnp.asarray(np.tile(a, (self.streams,) + (1,) *
+                                       (a.ndim - 1)))
+
         self._buckets: List[_Bucket] = []
         span_caps = []
         for idxs, padded, lens in bucket_arrays:
@@ -317,11 +386,11 @@ class StreamSession:
                    else int(span_cap))
             span_caps.append(cap)
             halo = max(1, -(-cap // self.chunk)) if self.prune else 0
-            b = _Bucket(idxs=list(idxs), queries=jnp.asarray(padded),
-                        qlens=jnp.asarray(lens, jnp.int32),
-                        lo=jnp.asarray(lo_all[np.asarray(idxs)]),
-                        hi=jnp.asarray(hi_all[np.asarray(idxs)]),
-                        zone=jnp.asarray(zone, jnp.int32),
+            b = _Bucket(idxs=list(idxs), queries=per_stream(padded),
+                        qlens=per_stream(np.asarray(lens, np.int32)),
+                        lo=per_stream(lo_all[np.asarray(idxs)]),
+                        hi=per_stream(hi_all[np.asarray(idxs)]),
+                        zone=per_stream(np.asarray(zone, np.int32)),
                         carry=None, halo=halo)
             b.carry = self._fresh_carry(b)
             if self.prune:
@@ -331,7 +400,7 @@ class StreamSession:
         self._max_halo = max(b.halo for b in self._buckets)
 
         # --- stream state ------------------------------------------------
-        self._buf = np.zeros((0,), np.int32)
+        self._buf = np.zeros(self._lead + (0,), np.int32)
         self._offset = 0             # samples advanced through the DP
         self._finalized = False
         self._flush_shift_pending = False   # mid-stream flush happened
@@ -348,6 +417,25 @@ class StreamSession:
         self.tiles_pruned_kim = 0
         self.tiles_pruned_keogh = 0
         self.tiles_processed = 0
+        self._reset_counters()
+
+    def _reset_counters(self):
+        self.launches = 0            # jitted tile steps run
+        self.streams_advanced = 0    # streams moved on by a tile, summed
+        self.alerts_fired = 0
+
+    def counters(self) -> dict:
+        """Telemetry since the session was opened or restored: device
+        steps launched, streams advanced (one per stream per tile), tiles
+        and alerts."""
+        return {"launches": self.launches,
+                "streams_advanced": self.streams_advanced,
+                "tiles": self.tiles_total, "alerts": self.alerts_fired}
+
+    @property
+    def _lead(self) -> tuple:
+        """Leading shape of fed data: ``(streams,)``, or none for one."""
+        return () if self.streams == 1 else (self.streams,)
 
     # ------------------------------------------------------------------
     # carry plumbing
@@ -410,20 +498,28 @@ class StreamSession:
 
     @property
     def samples_seen(self) -> int:
-        """Reference samples fed so far (including the buffered tail)."""
-        return self._offset + int(self._buf.shape[0])
+        """Reference samples fed so far per stream (including the
+        buffered tail)."""
+        return self._offset + int(self._buf.shape[-1])
 
     def feed(self, data) -> "StreamSession":
-        """Append reference samples; advance the DP by every whole tile."""
+        """Append reference samples — ``(samples,)``, or ``(streams,
+        samples)`` in a many-stream session; advance the DP by every
+        whole tile."""
+        with TraceAnnotation("stream.tick"):
+            return self._feed(data)
+
+    def _feed(self, data) -> "StreamSession":
         if self._finalized:
             raise RuntimeError("session is finalized (a pruned-mode flush "
                                "is terminal); snapshot/restore to branch "
                                "earlier")
         data = np.asarray(data)
-        if data.ndim != 1:
-            raise ValueError(f"feed() takes a 1-D chunk, got shape "
-                             f"{data.shape}")
-        if data.shape[0] == 0:
+        if data.shape[:-1] != self._lead or data.ndim != len(self._lead) + 1:
+            want = ("a 1-D chunk" if not self._lead else
+                    f"a ({self.streams}, samples) chunk, one row per stream")
+            raise ValueError(f"feed() takes {want}, got shape {data.shape}")
+        if data.shape[-1] == 0:
             return self
         if self._flush_shift_pending:
             self._flush_shift_pending = False
@@ -435,10 +531,10 @@ class StreamSession:
                     "aligned-boundary (offline or unflushed) run — the "
                     "top-1 distance/span stays exact. Poll results() "
                     "instead of flush() to read the tail without moving "
-                    "boundaries.", RuntimeWarning, stacklevel=2)
+                    "boundaries.", RuntimeWarning, stacklevel=3)
         if self._dtype is None:
             self._dtype = data.dtype
-            self._buf = np.zeros((0,), data.dtype)
+            self._buf = np.zeros(self._lead + (0,), data.dtype)
             if self._offset == 0:
                 # The carry's accumulator dtype depends on the stream's —
                 # rebuild the untouched fresh carries now that it is known.
@@ -447,12 +543,18 @@ class StreamSession:
         elif data.dtype != self._dtype:
             raise ValueError(f"stream dtype changed mid-flight: "
                              f"{self._dtype} -> {data.dtype}")
-        self._buf = np.concatenate([self._buf, data])
-        while self._buf.shape[0] >= self.chunk:
-            tile, self._buf = (self._buf[:self.chunk],
-                               self._buf[self.chunk:])
+        self._buf = np.concatenate([self._buf, data], axis=-1)
+        while self._buf.shape[-1] >= self.chunk:
+            tile, self._buf = (self._buf[..., :self.chunk],
+                               self._buf[..., self.chunk:])
             self._advance(tile, self.chunk)
         return self
+
+    def _padded_tail(self) -> np.ndarray:
+        """The buffered tail right-padded to one tile."""
+        padded = np.zeros(self._lead + (self.chunk,), self._buf.dtype)
+        padded[..., :self._buf.shape[-1]] = self._buf
+        return padded
 
     def flush(self) -> "StreamSession":
         """Destructively push the buffered tail through the DP.
@@ -460,14 +562,14 @@ class StreamSession:
         Exact mode keeps streaming afterwards (the carry exits at the true
         boundary); pruned mode finalizes the session — a partial tile
         breaks the halo-window alignment the bounds assume."""
-        if self._buf.shape[0]:
-            tail, self._buf = self._buf, self._buf[:0]
-            padded = np.zeros((self.chunk,), tail.dtype)
-            padded[:tail.shape[0]] = tail
-            self._advance(padded, int(tail.shape[0]))
+        tail = int(self._buf.shape[-1])
+        if tail:
+            padded = self._padded_tail()
+            self._buf = self._buf[..., :0]
+            self._advance(padded, tail)
             if self.prune:
                 self._finalized = True
-            elif tail.shape[0] % self.chunk:
+            elif tail % self.chunk:
                 # Exact mode keeps streaming, but the partial tile moved
                 # every later tile boundary — the next feed() warns when
                 # a k>1 heap rides this session (see module docstring).
@@ -475,65 +577,70 @@ class StreamSession:
         return self
 
     def _advance(self, tile_np: np.ndarray, clen: int):
-        """Advance every bucket by one (possibly right-padded) tile."""
+        """Advance every bucket of every stream by one (possibly
+        right-padded) tile."""
         j0 = self._offset
         if self.prune:
             self._advance_pruned(tile_np, clen, j0)
         else:
             tile = jnp.asarray(tile_np)
+            alerts = []
             for b in self._buckets:
-                out = self._step_exact(b, tile, j0, clen, b.carry)
-                b.carry, lrow, lstart = out
-                if self.alert_threshold is not None:
-                    self._emit_alerts(b, lrow, lstart, j0, clen)
+                with TraceAnnotation("stream.launch"):
+                    b.carry, alert = self._step_exact(
+                        b, tile, j0, clen, b.carry,
+                        alert=self.alert_threshold is not None)
+                self.launches += 1
+                alerts.append(alert)
+            if self.alert_threshold is not None:
+                self._emit_alerts(alerts, j0, clen)
             self.tiles_processed += 1      # exact mode runs every tile
         self.tiles_total += 1
+        self.streams_advanced += self.streams
         self._offset += clen
 
-    def _step_exact(self, b: _Bucket, tile, j0: int, clen: int, carry):
-        """One exact-mode tile for one bucket — pure in ``carry``."""
-        j0_t = jnp.int32(j0)
-        m_tot = jnp.int32(j0 + clen)
-        cl = jnp.int32(clen)
-        if self.impl == "pallas":
-            kc = carry[:-3] if self._wants_heap else carry
-            heap = carry[-3:] if self._wants_heap else None
-            new, lrow, lstart = _pallas_step(
-                b.queries, tile, b.qlens, kc, heap, j0_t, cl, b.zone,
-                metric=self.metric, block_q=self.block_q,
-                block_m=self.block_m, k=self._k,
-                excl_span=self.excl_mode == "span", track=self._track,
-                want_lastrow=self._want_lastrow,
-                with_heap=self._wants_heap)
-            return new, lrow, lstart
-        if self._wants_heap:
-            return _heap_step(b.queries, tile, b.qlens, carry, j0_t, m_tot,
-                              cl, b.lo, b.hi, b.zone, metric=self.metric,
-                              k=self._k, excl_span=self.excl_mode == "span",
-                              track=self._track,
-                              lastrow=self._want_lastrow)
-        return (_plain_step(b.queries, tile, b.qlens, carry, j0_t, m_tot,
-                            cl, b.lo, b.hi, metric=self.metric),
-                None, None)
+    def _step_exact(self, b: _Bucket, tile, j0: int, clen: int, carry, *,
+                    alert: bool = False):
+        """One exact-mode tile for one bucket — pure in ``carry``. Returns
+        the new carry and, with ``alert``, the rows' device-side alerts."""
+        thr = self._device_threshold(self._acc(b)) if alert else None
+        return _exact_step(
+            b.queries, tile, b.qlens, carry, jnp.int32(j0),
+            jnp.int32(clen), b.lo, b.hi, b.zone, thr, impl=self.impl,
+            metric=self.metric, block_q=self.block_q, block_m=self.block_m,
+            k=self._k, excl_span=self.excl_mode == "span", track=self._track,
+            want_lastrow=self._want_lastrow, with_heap=self._wants_heap,
+            alert=alert)
 
-    def _emit_alerts(self, b: _Bucket, lrow, lstart, j0: int, clen: int):
+    def _device_threshold(self, acc):
+        """The alert threshold in the accumulator's dtype, compared on the
+        device as NumPy compares an ``acc`` cost with the Python float:
+        exactly for integer costs (so floored), in ``acc`` for floating
+        ones."""
         thr = self.alert_threshold
-        lr = np.asarray(lrow)[:, :clen]
-        ls = None if lstart is None else np.asarray(lstart)[:, :clen]
-        hits = lr <= thr
-        for row, orig in enumerate(b.idxs):
-            cols = np.nonzero(hits[row])[0]
-            if not cols.size:
-                continue
-            best_col = int(cols[np.argmin(lr[row, cols])])
-            ev = AlertEvent(
-                query=orig, distance=lr[row, best_col].item(),
-                start=int(ls[row, best_col]) if ls is not None else -1,
-                end=j0 + best_col, tile_start=j0, tile_end=j0 + clen,
-                hits=int(cols.size))
-            self.alerts.append(ev)
-            if self.on_alert is not None:
-                self.on_alert(ev)
+        if np.issubdtype(acc, np.integer):
+            info = np.iinfo(acc)
+            thr = np.clip(np.floor(thr), info.min, info.max)
+        return jnp.asarray(thr, acc)
+
+    def _emit_alerts(self, alerts, j0: int, clen: int):
+        """Fetch a tile's device-side alerts, every bucket's at once, and
+        record one ``AlertEvent`` per row that has a hit."""
+        with TraceAnnotation("stream.alerts"):
+            fetched = jax.device_get(alerts)
+            for b, (hits, dist, col, start) in zip(self._buckets, fetched):
+                nb = len(b.idxs)
+                for row in np.flatnonzero(hits):
+                    stream, i = divmod(int(row), nb)
+                    ev = AlertEvent(
+                        query=b.idxs[i], distance=dist[row].item(),
+                        start=int(start[row]), end=j0 + int(col[row]),
+                        tile_start=j0, tile_end=j0 + clen,
+                        hits=int(hits[row]), stream=stream)
+                    self.alerts.append(ev)
+                    self.alerts_fired += 1
+                    if self.on_alert is not None:
+                        self.on_alert(ev)
 
     # ------------------------------------------------------------------
     # online pruning (LB cascade against the live heap thresholds)
@@ -564,6 +671,7 @@ class StreamSession:
                                                (b.thr, b.carry))
             decisions.append(decision)
             if decision == "processed":
+                self.launches += 1
                 b.carry = heap
                 b.thr = np.asarray(heap[0][:, -1], np.float64)
         if "processed" in decisions:
@@ -624,9 +732,8 @@ class StreamSession:
         tail = self._buf
         for bi, b in enumerate(self._buckets):
             carry = b.carry
-            if tail.shape[0]:
-                padded = np.zeros((self.chunk,), tail.dtype)
-                padded[:tail.shape[0]] = tail
+            if tail.shape[-1]:
+                padded = self._padded_tail()
                 if self.prune:
                     # Peek with copies of (thr, heap); ring/cache untouched.
                     saved_env = list(self._env_tail)
@@ -642,17 +749,18 @@ class StreamSession:
                     finally:
                         self._env_tail = saved_env
                 else:
-                    carry, _, _ = self._step_exact(
+                    carry, _ = self._step_exact(
                         b, jnp.asarray(padded), self._offset,
-                        int(tail.shape[0]), carry)
+                        int(tail.shape[-1]), carry)
             carries[bi] = carry
         return self._assemble(carries)
 
     def _assemble(self, carries) -> StreamResult:
         kk = self._k
-        out_d = [None] * self._nq
-        out_p = [None] * self._nq
-        out_s = [None] * self._nq
+        S = self.streams
+        out_d = [None] * (S * self._nq)     # stream-major, like the rows
+        out_p = [None] * (S * self._nq)
+        out_s = [None] * (S * self._nq)
         wants_pos = (self._wants_heap or self.impl == "pallas") and \
             (self.top_k is not None or self.return_positions
              or self.return_spans)
@@ -681,19 +789,22 @@ class StreamSession:
             else:
                 d = np.asarray(carry[-1])[:, None]
                 p = s = np.full_like(d, -1, dtype=np.int32)
-            for row, orig in enumerate(b.idxs):
-                out_d[orig] = d[row]
-                out_p[orig] = p[row]
-                out_s[orig] = s[row]
-        dists = np.stack(out_d)
-        poss = np.stack(out_p)
-        starts = np.stack(out_s)
-        if self.top_k is None:          # unstacked top-1 / plain
-            dists, poss, starts = dists[:, 0], poss[:, 0], starts[:, 0]
-        else:
-            dists, poss, starts = dists[:, :kk], poss[:, :kk], starts[:, :kk]
-        if self._single:
+            for row in range(d.shape[0]):
+                stream, i = divmod(row, len(b.idxs))
+                at = stream * self._nq + b.idxs[i]
+                out_d[at], out_p[at], out_s[at] = d[row], p[row], s[row]
+        dists, poss, starts = (np.stack(o).reshape((S, self._nq, -1))
+                               for o in (out_d, out_p, out_s))
+        if S == 1:                      # no stream axis
             dists, poss, starts = dists[0], poss[0], starts[0]
+        if self.top_k is None:          # unstacked top-1 / plain
+            dists, poss, starts = dists[..., 0], poss[..., 0], starts[..., 0]
+        else:
+            dists, poss, starts = (dists[..., :kk], poss[..., :kk],
+                                   starts[..., :kk])
+        if self._single:
+            dists, poss, starts = ((x[0] if S == 1 else x[:, 0])
+                                   for x in (dists, poss, starts))
         return StreamResult(
             distances=dists,
             positions=poss if wants_pos else None,
@@ -729,6 +840,7 @@ class StreamSession:
             dtype=None if self._dtype is None else np.dtype(
                 self._dtype).name,
             nq=self._nq, single=self._single, ragged=self._ragged,
+            streams=self.streams,
             tiles=[self.tiles_total, self.tiles_pruned_kim,
                    self.tiles_pruned_keogh, self.tiles_processed],
             env_tail=list(getattr(self, "_env_tail", [])),
@@ -764,10 +876,11 @@ class StreamSession:
                 ref_key=None) -> "StreamSession":
         """Rebuild a session from ``snapshot()`` output (or a loaded
         ``np.load`` of it). ``on_alert``/``cache`` are not serialized —
-        pass them again; ``ref_key`` overrides the snapshotted key (e.g.
+        pass them again (nor are the alert log and ``counters()``, which
+        start afresh); ``ref_key`` overrides the snapshotted key (e.g.
         when the cache identity changed across processes)."""
         meta = json.loads(str(np.asarray(snap["meta"])[()]))
-        if meta["version"] != _SNAP_VERSION:
+        if meta["version"] not in (1, _SNAP_VERSION):
             raise ValueError(f"snapshot version {meta['version']} not "
                              f"supported (expected {_SNAP_VERSION})")
         self = cls.__new__(cls)
@@ -786,7 +899,9 @@ class StreamSession:
         self.on_alert = on_alert
         self.block_q = meta["block_q"]
         self.block_m = meta["block_m"]
+        self.streams = meta.get("streams", 1)
         self.alerts = []
+        self._reset_counters()
         self._derive_modes()
         self._nq = meta["nq"]
         self._single = meta["single"]

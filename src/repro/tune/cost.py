@@ -110,16 +110,20 @@ class KernelCostModel:
 
     @staticmethod
     def vmem_words(block_q: int, block_m: int, n: int,
-                   span: bool = False, lastrow: bool = False) -> int:
+                   span: bool = False, lastrow: bool = False,
+                   rowref: bool = False) -> int:
         """Accumulator words live per pallas grid cell — identical to the
         formula in the ``sdtw_pallas`` docstring (boundary column in
         persistent scratch + ~3 (plain) / ~6 (span) live row vectors,
         span mode adding the int32 start lanes), plus the double-buffered
-        ``return_lastrow`` output block and its start lane."""
+        ``return_lastrow`` output block and its start lane, and the
+        double-buffered per-row reference tile."""
         words = (block_q * (6 * block_m + 5 * n) if span
                  else block_q * (3 * block_m + 3 * n))
         if lastrow:
             words += 2 * block_q * block_m * (2 if span else 1)
+        if rowref:
+            words += 2 * block_q * block_m
         return words
 
     # -- per-regime cost (microseconds) ---------------------------------
@@ -151,20 +155,23 @@ class KernelCostModel:
 
     def pallas_us(self, nq: int, n: int, m: int, block_q: int,
                   block_m: int, scan_scheme: str, row_tile: int,
-                  span: bool = False, lastrow: bool = False) -> float:
+                  span: bool = False, lastrow: bool = False,
+                  rowref: bool = False) -> float:
         """One pallas launch over the full grid; ``inf`` when the config
         busts the VMEM budget (never a candidate)."""
         be = self.backend
-        if self.vmem_words(block_q, block_m, n, span, lastrow) \
+        if self.vmem_words(block_q, block_m, n, span, lastrow, rowref) \
                 > be.vmem_budget_words:
             return float("inf")
         q_tiles = -(-nq // block_q)
         m_tiles = -(-max(m, block_m) // block_m)
         tiles = q_tiles * m_tiles
-        # HBM streaming: the reference is re-read once per query tile,
-        # queries once per reference tile, boundary column stays in VMEM
-        # scratch (free); 4-byte accumulator words.
-        hbm_bytes = 4 * (q_tiles * m + m_tiles * block_q * n)
+        # HBM streaming: the reference is re-read once per query tile (a
+        # per-row reference once per query), queries once per reference
+        # tile, boundary column stays in VMEM scratch (free); 4-byte
+        # accumulator words.
+        ref_rows = q_tiles * block_q if rowref else q_tiles
+        hbm_bytes = 4 * (ref_rows * m + m_tiles * block_q * n)
         from repro.launch.roofline import kernel_roofline
         hbm_us = kernel_roofline(
             0, hbm_bytes, cells_per_s=1.0,
@@ -240,7 +247,8 @@ class KernelCostModel:
         return self.chunk_candidates(nq, n, m)[0][0]
 
     def pallas_candidates(self, nq: int, n: int, m: int,
-                          span: bool = False, lastrow: bool = False) -> list:
+                          span: bool = False, lastrow: bool = False,
+                          rowref: bool = False) -> list:
         """Ranked ``[((block_q, block_m, scheme, row_tile), us), ...]``.
 
         The candidate set stays deliberately small (it seeds the measured
@@ -272,14 +280,16 @@ class KernelCostModel:
                 rt = self.tpu_row_tile(bq, bm) if compiled else 1
                 for scheme in schemes:
                     us = self.pallas_us(nq, n, m, bq, bm, scheme, rt,
-                                        span=span, lastrow=lastrow)
+                                        span=span, lastrow=lastrow,
+                                        rowref=rowref)
                     if math.isfinite(us):
                         scored.append(((bq, bm, scheme, rt), us))
         scored.sort(key=lambda t: t[1])
         if not scored:
             raise ValueError(
                 f"no pallas config fits the VMEM budget for nq={nq} "
-                f"n={n} m={m} (span={span}, lastrow={lastrow})")
+                f"n={n} m={m} (span={span}, lastrow={lastrow}, "
+                f"rowref={rowref})")
         return scored
 
     @classmethod
@@ -291,9 +301,10 @@ class KernelCostModel:
         return 8 if cls.block_vregs(block_q, block_m) <= 2 else 2
 
     def best_pallas(self, nq: int, n: int, m: int, span: bool = False,
-                    lastrow: bool = False) -> TunedConfig:
+                    lastrow: bool = False,
+                    rowref: bool = False) -> TunedConfig:
         (bq, bm, scheme, rt), us = self.pallas_candidates(
-            nq, n, m, span=span, lastrow=lastrow)[0]
+            nq, n, m, span=span, lastrow=lastrow, rowref=rowref)[0]
         return TunedConfig(impl="pallas", block_q=bq, block_m=bm,
                            scan_scheme=scheme, row_tile=rt, score_us=us)
 

@@ -82,11 +82,12 @@ def _overlay(base: TunedConfig, entry: TunedConfig) -> TunedConfig:
 def resolve(nq: int, n: int, m: int, *, backend: Optional[str] = None,
             metric: str = "abs_diff", dtype: str = "int32",
             mode: str = "model", span: bool = False,
-            lastrow: bool = False) -> Resolution:
+            lastrow: bool = False, rowref: bool = False) -> Resolution:
     """The oracle: LRU -> table -> cost model (-> measured search under
     ``mode='measure'``).  Costs are evaluated at the bucket's pow-2
-    shape so every shape in a bucket shares one decision; ``span`` and
-    ``lastrow`` (the kernel variant) size the VMEM working set."""
+    shape so every shape in a bucket shares one decision; ``span``,
+    ``lastrow`` and ``rowref`` (the kernel variant) size the VMEM
+    working set."""
     backend = canonical_backend(backend)
     key = bucket_key(backend, metric, dtype, nq, n, m)
 
@@ -96,7 +97,8 @@ def resolve(nq: int, n: int, m: int, *, backend: Optional[str] = None,
         nn = _pow2_bucket(max(1, n))
         nm = _pow2_bucket(max(1, m))
         ranked = tuple(model.rank_impls(nb, nn, nm))
-        pal = model.best_pallas(nb, nn, nm, span=span, lastrow=lastrow)
+        pal = model.best_pallas(nb, nn, nm, span=span, lastrow=lastrow,
+                                rowref=rowref)
         chunk = model.best_chunk(nb, nn, nm)
         cfg = TunedConfig(
             impl=ranked[0][0], block_q=pal.block_q, block_m=pal.block_m,
@@ -117,7 +119,7 @@ def resolve(nq: int, n: int, m: int, *, backend: Optional[str] = None,
         return Resolution(dataclasses.replace(cfg, source=source),
                           ranked, source)
 
-    return cached((key, span, lastrow, mode), compute)
+    return cached((key, span, lastrow, rowref, mode), compute)
 
 
 # ---------------------------------------------------------------------------
@@ -127,13 +129,13 @@ def resolve(nq: int, n: int, m: int, *, backend: Optional[str] = None,
 def tuned_blocks(b: int, m: int, *, n: int, backend: Optional[str] = None,
                  metric: str = "abs_diff", dtype: str = "int32",
                  mode: str = "model", span: bool = False,
-                 lastrow: bool = False) -> tuple:
+                 lastrow: bool = False, rowref: bool = False) -> tuple:
     """Kernel block knobs for ``resolve_blocks``:
     ``(block_q, block_m, scan_scheme, row_tile)``.  'measure' downgrades
     to the table (see module doc — this is called at trace time)."""
     res = resolve(b, n, m, backend=backend, metric=metric, dtype=dtype,
                   mode="model" if mode == "measure" else mode, span=span,
-                  lastrow=lastrow)
+                  lastrow=lastrow, rowref=rowref)
     c = res.config
     return c.block_q, c.block_m, c.scan_scheme, c.row_tile
 

@@ -18,7 +18,10 @@ module's own reference code — so there is exactly one definition of
     bounded memory;
   * top-K:      ``greedy_topk`` / ``greedy_topk_spans`` — best-first
     select-then-suppress on the full last row, by end distance or by span
-    overlap.
+    overlap;
+  * alerts:     ``sdtw_last_row`` (the cost of the best match ending at
+    each column) and ``tile_alerts``, a stream monitor's threshold alerts
+    read off it tile by tile.
 
 (The former second oracle, the pure-jnp scan of ``repro.kernels.sdtw.ref``,
 is gone: its only non-test use was as a benchmark baseline, which now
@@ -37,7 +40,8 @@ from repro.core.sdtw_ref import dtw_ref, sdtw_matrix, sdtw_ref  # noqa: F401
 __all__ = [
     "sdtw_ref", "sdtw_matrix", "dtw_ref",
     "sdtw_span_matrix", "sdtw_span", "sdtw_end",
-    "sdtw_path", "greedy_topk", "greedy_topk_spans",
+    "sdtw_path", "greedy_topk", "greedy_topk_spans", "sdtw_last_row",
+    "tile_alerts",
 ]
 
 
@@ -207,4 +211,36 @@ def greedy_topk_spans(query, reference, k: int, zone: int,
         else:
             hit = np.abs(np.arange(m) - j) <= zone
         row[hit] = np.inf
+    return out
+
+
+def sdtw_last_row(query, reference, metric: str = "abs_diff") -> np.ndarray:
+    """The DP's last row, swept one row at a time: the cost of the best
+    match of ``query`` ending at each column of ``reference`` (float64)."""
+    q = np.asarray(query, np.float64)
+    r = np.asarray(reference, np.float64)
+    prev = _dist(q[0], r, metric)
+    for i in range(1, len(q)):
+        di = _dist(q[i], r, metric)
+        cur = np.empty_like(prev)
+        cur[0] = prev[0] + di[0]
+        for j in range(1, len(r)):
+            cur[j] = di[j] + min(prev[j - 1], cur[j - 1], prev[j])
+        prev = cur
+    return prev
+
+
+def tile_alerts(last_row, threshold, chunk: int) -> list:
+    """A threshold monitor's alerts over a last row fed in ``chunk``-wide
+    tiles: ``[(tile_start, hits, distance, end)]`` for every tile with a
+    column at or under ``threshold`` — the count of such columns, their
+    least cost and the leftmost column attaining it."""
+    row = np.asarray(last_row, np.float64)
+    out = []
+    for t0 in range(0, len(row), chunk):
+        cols = np.flatnonzero(row[t0:t0 + chunk] <= threshold)
+        if cols.size:
+            best = cols[np.argmin(row[t0 + cols])]
+            out.append((t0, int(cols.size), float(row[t0 + best]),
+                        t0 + int(best)))
     return out

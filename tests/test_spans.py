@@ -20,13 +20,15 @@ from repro.serve import RequestTrace, Router, RouterConfig, Telemetry
 SERVE = ("serve.submit", "serve.window", "serve.group", "serve.execute",
          "serve.deliver")
 ENGINE = ("engine.ragged", "engine.prepare", "engine.launch")
+STREAM = ("stream.tick", "stream.launch", "stream.alerts")
 
 Span = collections.namedtuple("Span", "line name start end stats")
 
 
 def _traced(fn):
     """Run ``fn()`` under the profiler; return its result and the spans
-    of the program (``serve.*``, ``engine.*``) on the host plane."""
+    of the program (``serve.*``, ``engine.*``, ``stream.*``) on the host
+    plane."""
     with tempfile.TemporaryDirectory() as tdir:
         jax.profiler.start_trace(tdir)
         try:
@@ -41,7 +43,7 @@ def _traced(fn):
             continue
         for i, line in enumerate(plane.lines):
             for ev in line.events:
-                if ev.name in SERVE + ENGINE:
+                if ev.name in SERVE + ENGINE + STREAM:
                     spans.append(Span(i, ev.name, ev.start_ns,
                                       ev.start_ns + ev.duration_ns,
                                       dict(ev.stats)))
@@ -230,3 +232,35 @@ def test_failed_group_is_accounted(rng):
     assert (snap.mean_admit_wait_us + snap.mean_pool_wait_us
             + snap.mean_engine_us + snap.mean_deliver_us) == \
         pytest.approx(snap.mean_latency_us, rel=1e-9)
+
+
+@pytest.mark.parametrize("impl", ["rowscan", "pallas"])
+def test_stream_tick_spans_and_counters(impl, rng):
+    """Each ``feed`` of a many-stream session is one ``stream.tick``;
+    inside it, one ``stream.launch`` per tile advances every stream, and
+    one ``stream.alerts`` per tile fetches the tile's alerts after its
+    launch. The session's counters count the same."""
+    q = rng.integers(-40, 40, (3, 12)).astype(np.int32)
+    data = rng.integers(-40, 40, (2, 64)).astype(np.int32)
+    data[1, 20:32] = q[2]
+    s = engine.stream(q, streams=2, chunk=16, impl=impl,
+                      alert_threshold=0)
+
+    def feed():
+        s.feed(data[:, :40])        # two whole tiles, 8 samples buffered
+        s.feed(data[:, 40:])        # two more tiles
+        return s.counters()
+
+    counters, spans = _traced(feed)
+    ticks = sorted(_named(spans, "stream.tick"), key=lambda x: x.start)
+    launches = _named(spans, "stream.launch")
+    fetches = _named(spans, "stream.alerts")
+    assert len(ticks) == 2 and len(launches) == len(fetches) == 4
+    assert [sum(_inside(x, t) for x in launches) for t in ticks] == [2, 2]
+    for f in fetches:
+        assert any(_inside(f, t) for t in ticks)
+        assert any(x.end <= f.start for x in launches)
+    assert counters == {"launches": 4, "streams_advanced": 8, "tiles": 4,
+                        "alerts": 1}
+    ev, = s.alerts
+    assert (ev.stream, ev.query, ev.distance, ev.end) == (1, 2, 0, 31)

@@ -173,3 +173,39 @@ def test_row_blocks_crossing_a_lane_window():
         got = (float(d[i]), int(s[i]), int(e[i]))
         assert got == want, (i, got, want)
         assert float(plain[i]) == want[0]
+
+
+def test_fleet_tile_step_compiles_for_v5e(one_chip, topo):
+    """A many-stream session's tile step at the ECG monitoring
+    deployment's shape — 1,024 streams × 16 templates of 512, ticks of
+    360 samples, alerts reduced on the device — compiles as one program
+    around one Mosaic kernel, with the tuner's block for a per-row
+    reference: a tall block that packs rows of several streams."""
+    from repro.kernels.sdtw import ops
+    from repro.stream import session
+    streams, templates, n, tick = 1024, 16, 512, 360
+    rows = streams * templates
+
+    def spec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(platforms, "tpu_device_kind",
+                   lambda: topo.devices[0].device_kind)
+        mp.setattr(ops, "interpret_mode", lambda backend=None: False)
+        mp.setattr(cost, "_MODELS", {})
+        clear_tuning_cache()
+        try:
+            bq, bm, _, _ = tuned_blocks(rows, tick, n=n, backend="tpu",
+                                        lastrow=True, rowref=True)
+            compiled = session._exact_step.lower(
+                spec(rows, n), spec(streams, tick), spec(rows),
+                (spec(rows, n), spec(rows), spec(rows)), spec(), spec(),
+                spec(rows), spec(rows), spec(rows), spec(), impl="pallas",
+                metric="abs_diff", block_q=None, block_m=None, k=1,
+                excl_span=False, track=False, want_lastrow=True,
+                with_heap=False, alert=True).compile()
+        finally:
+            clear_tuning_cache()
+    assert bq >= 2 * templates and bm >= tick
+    assert compiled.as_text().count("tpu_custom_call") >= 1
