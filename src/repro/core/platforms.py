@@ -77,15 +77,15 @@ class RowChainPrice:
 
     A DP row is a chain of dependent steps (``kernels/sdtw/sdtw.py``,
     ``one_row``): one-hot lane picks of the query value and the boundary
-    entry, log2(block_m) Hillis-Steele lane-shift steps, and the pick of
-    the exit lane; the next row waits for it. The scan runs along lanes
-    only, and the ``block_q`` rows ride the same vector instructions, so
-    a row costs the larger of the chain's latency and the vector work it
-    carries:
+    entry, ceil(log2(block_m)) Hillis-Steele lane-shift steps (9 for a
+    384-lane block, as for 512), and the pick of the exit lane; the next
+    row waits for it. The scan runs along lanes only, and the ``block_q``
+    rows ride the same vector instructions, so a row costs the larger of
+    the chain's latency and the vector work it carries:
 
-        max(lat_fixed_us + lat_step_us * log2(block_m),
+        max(lat_fixed_us + lat_step_us * ceil(log2(block_m)),
             vreg_step_us * (ceil(block_q/8) * ceil(block_m/128)
-                            * log2(block_m)
+                            * ceil(log2(block_m))
                             + pick_vreg_steps * ceil(block_q/8)))
 
     the latency times ``span_lat_mult`` and the work times

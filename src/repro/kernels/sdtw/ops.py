@@ -22,7 +22,7 @@ to a working-set budget, the work-efficient ``"assoc"`` scan, and no row
 unrolling (XLA-CPU gains nothing from it). With ``tune='model'`` (what
 ``engine.sdtw`` passes by default) the unset knobs come from the
 ``repro.tune`` oracle instead: a tuning-table hit for this (backend,
-metric, dtype, pow-2 shape bucket), else the analytical cost model's
+metric, dtype, shape bucket), else the analytical cost model's
 ranked pick (``tune='measure'`` is downgraded to the table here — this
 resolves at trace time, where measuring would time tracing; the engine
 runs measured refinement *before* dispatch). Explicit knobs always win.

@@ -182,18 +182,19 @@ class _Bucket:
 
 
 def _pallas_step(queries, tile, qlens, kcarry, heap, j0, clen, zone, *,
-                 metric, block_q, block_m, k, excl_span, track,
-                 want_lastrow, with_heap):
+                 metric, block_q, block_m, scan_scheme, row_tile, k,
+                 excl_span, track, want_lastrow, with_heap):
     """One streamed tile through the Pallas kernel: advance the kernel
     chunk carry and — when the session consumes candidate rows — fold the
     in-kernel last-row capture into the top-K heap with the identical
     per-tile ``topk_merge`` the rowscan path runs, so pallas sessions
-    reproduce the offline chunked heap bitwise (int32). The launch block
-    comes from the tuner, keyed on the call's shape."""
+    reproduce the offline chunked heap bitwise (int32). Knobs left
+    ``None`` come from the tuner, keyed on the call's shape."""
     from repro.kernels.sdtw import sdtw_pallas
     out = sdtw_pallas(queries, tile, qlens, metric, block_q=block_q,
                       block_m=block_m, carry=kcarry, return_carry=True,
                       ref_offset=j0, ref_len=clen, track_start=track,
+                      scan_scheme=scan_scheme, row_tile=row_tile,
                       return_lastrow=want_lastrow, tune="model")
     if not want_lastrow:
         _, kc = out
@@ -247,11 +248,11 @@ def _heap_step(queries, tile, qlens, carry, j0, m_total, clen, lo, hi, zone,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "impl", "metric", "block_q", "block_m", "k", "excl_span", "track",
-    "want_lastrow", "with_heap", "alert"))
+    "impl", "metric", "block_q", "block_m", "scan_scheme", "row_tile", "k",
+    "excl_span", "track", "want_lastrow", "with_heap", "alert"))
 def _exact_step(queries, tile, qlens, carry, j0, clen, lo, hi, zone, thr, *,
-                impl, metric, block_q, block_m, k, excl_span, track,
-                want_lastrow, with_heap, alert):
+                impl, metric, block_q, block_m, scan_scheme, row_tile, k,
+                excl_span, track, want_lastrow, with_heap, alert):
     """One exact-mode tile for one bucket, as one jitted step: advance the
     carry and, with ``alert``, reduce the tile's candidate rows to each
     row's alert (``_tile_alerts``). A 2-D ``tile`` (streams, C) is one
@@ -264,8 +265,9 @@ def _exact_step(queries, tile, qlens, carry, j0, clen, lo, hi, zone, thr, *,
         heap = carry[-3:] if with_heap else None
         new, lrow, lstart = _pallas_step(
             queries, tile, qlens, kc, heap, j0, clen, zone, metric=metric,
-            block_q=block_q, block_m=block_m, k=k, excl_span=excl_span,
-            track=track, want_lastrow=want_lastrow, with_heap=with_heap)
+            block_q=block_q, block_m=block_m, scan_scheme=scan_scheme,
+            row_tile=row_tile, k=k, excl_span=excl_span, track=track,
+            want_lastrow=want_lastrow, with_heap=with_heap)
     elif with_heap:
         new, lrow, lstart = _heap_step(
             queries, tile, qlens, carry, j0, j0 + clen, clen, lo, hi, zone,
@@ -423,14 +425,20 @@ class StreamSession:
         self.launches = 0            # jitted tile steps run
         self.streams_advanced = 0    # streams moved on by a tile, summed
         self.alerts_fired = 0
+        self.lanes_fed = 0           # reference samples of kernel launches
+        self.lanes_launched = 0      # the padded tile widths they ran
 
     def counters(self) -> dict:
         """Telemetry since the session was opened or restored: device
         steps launched, streams advanced (one per stream per tile), tiles
-        and alerts."""
+        and alerts; and, summed over kernel launches, the reference
+        samples each was fed and the tile width it ran (the tile rounded
+        up to whole ``block_m`` blocks), whose gap is lane padding."""
         return {"launches": self.launches,
                 "streams_advanced": self.streams_advanced,
-                "tiles": self.tiles_total, "alerts": self.alerts_fired}
+                "tiles": self.tiles_total, "alerts": self.alerts_fired,
+                "lanes_fed": self.lanes_fed,
+                "lanes_launched": self.lanes_launched}
 
     @property
     def _lead(self) -> tuple:
@@ -587,10 +595,14 @@ class StreamSession:
             alerts = []
             for b in self._buckets:
                 with TraceAnnotation("stream.launch"):
-                    b.carry, alert = self._step_exact(
+                    b.carry, alert, blocks = self._step_exact(
                         b, tile, j0, clen, b.carry,
                         alert=self.alert_threshold is not None)
                 self.launches += 1
+                if blocks is not None:
+                    bm = blocks[1]
+                    self.lanes_fed += clen
+                    self.lanes_launched += -(-tile.shape[-1] // bm) * bm
                 alerts.append(alert)
             if self.alert_threshold is not None:
                 self._emit_alerts(alerts, j0, clen)
@@ -602,15 +614,38 @@ class StreamSession:
     def _step_exact(self, b: _Bucket, tile, j0: int, clen: int, carry, *,
                     alert: bool = False):
         """One exact-mode tile for one bucket — pure in ``carry``. Returns
-        the new carry and, with ``alert``, the rows' device-side alerts."""
+        the new carry, with ``alert`` the rows' device-side alerts (else
+        None), and the kernel's ``(block_q, block_m, scan_scheme,
+        row_tile)`` (None off the kernel)."""
         thr = self._device_threshold(self._acc(b)) if alert else None
-        return _exact_step(
+        blocks = self._launch_blocks(b, tile)
+        bq, bm, scheme, rt = (blocks if blocks is not None
+                              else (self.block_q, self.block_m, None, None))
+        new, alerts = _exact_step(
             b.queries, tile, b.qlens, carry, jnp.int32(j0),
             jnp.int32(clen), b.lo, b.hi, b.zone, thr, impl=self.impl,
-            metric=self.metric, block_q=self.block_q, block_m=self.block_m,
-            k=self._k, excl_span=self.excl_mode == "span", track=self._track,
-            want_lastrow=self._want_lastrow, with_heap=self._wants_heap,
-            alert=alert)
+            metric=self.metric, block_q=bq, block_m=bm, scan_scheme=scheme,
+            row_tile=rt, k=self._k, excl_span=self.excl_mode == "span",
+            track=self._track, want_lastrow=self._want_lastrow,
+            with_heap=self._wants_heap, alert=alert)
+        return new, alerts, blocks
+
+    def _launch_blocks(self, b: _Bucket, tile):
+        """The kernel block of one tile step, resolved here with the
+        arguments ``sdtw_pallas`` would resolve it with (the session's
+        explicit ``block_q``/``block_m`` win, the tuner fills the rest)
+        and handed to the launch whole, so the step runs exactly this
+        block. None on the rowscan path."""
+        if self.impl != "pallas":
+            return None
+        from repro.kernels.sdtw import interpret_mode, resolve_blocks
+        rows, n = b.queries.shape
+        return resolve_blocks(
+            rows, tile.shape[-1], self.block_q, self.block_m, None, None,
+            interpret_mode(), n=n, metric=self.metric,
+            dtype=str(jnp.result_type(b.queries, tile)), tune="model",
+            span=self._track, lastrow=self._want_lastrow,
+            rowref=tile.ndim == 2)
 
     def _device_threshold(self, acc):
         """The alert threshold in the accumulator's dtype, compared on the
@@ -749,7 +784,7 @@ class StreamSession:
                     finally:
                         self._env_tail = saved_env
                 else:
-                    carry, _ = self._step_exact(
+                    carry, _, _ = self._step_exact(
                         b, jnp.asarray(padded), self._offset,
                         int(tail.shape[-1]), carry)
             carries[bi] = carry

@@ -1,8 +1,8 @@
 """repro.tune — cost-model-driven autotuning for kernel/dispatch config.
 
 Two stages (see ``tuner``): an analytical ``KernelCostModel`` ranks
-candidate configurations per (backend, metric, dtype, pow-2 shape
-bucket); a short measured search optionally refines the top candidates,
+candidate configurations per (backend, metric, dtype, shape bucket:
+``bucket_key``); a short measured search optionally refines the top candidates,
 with winners persisted in a versioned JSON ``TuningTable`` (shipped
 defaults under ``tables/``) behind a per-process LRU (``cache``).
 

@@ -26,10 +26,10 @@ is priced in microseconds from the calibrated per-backend constants of
     passes, each a memory sweep over the block, weighted by the scan
     scheme ('assoc' is the cheap one there) — small tiles win.  The
     compiled TPU kernel (``RowChainPrice``): N DP rows per tile, each
-    the larger of its serial chain's latency (picks plus log2(block_m)
-    lane-shift steps) and the vregs its ``block_q`` rows carry through
-    those steps — tall blocks win until the vector work outweighs the
-    chain.  Configs whose VMEM working set ``vmem_words`` exceeds the
+    the larger of its serial chain's latency (picks plus
+    ceil(log2(block_m)) lane-shift steps) and the vregs its ``block_q``
+    rows carry through those steps — tall blocks win until the vector
+    work outweighs the chain.  Configs whose VMEM working set ``vmem_words`` exceeds the
     backend budget are rejected outright — the formula
     ``kernels/sdtw/ops.py`` documents.
 
@@ -75,16 +75,39 @@ def _pow2_bucket(x: int) -> int:
     return 1 << max(0, int(x) - 1).bit_length()
 
 
+#: Lanes of a TPU vector register: the compiled kernel's reference tile
+#: is a whole number of them, not necessarily a power of two.
+TPU_LANES = 128
+
+
+def m_bucket(m: int, backend: str) -> int:
+    """The reference-length bucket a decision is priced and cached at.
+
+    On the TPU a reference up to the largest ``block_m`` rung is rounded
+    up to whole 128-lane vregs, so a short reference (a 360-sample
+    monitoring tick) is priced and launched as 384 lanes instead of
+    512; longer references, and every reference in interpret mode, take
+    the next power of two.
+    """
+    m = max(1, int(m))
+    if backend == "tpu":
+        lanes = -(-m // TPU_LANES) * TPU_LANES
+        if lanes <= max(KernelCostModel.BLOCK_M_CANDIDATES):
+            return lanes
+    return _pow2_bucket(m)
+
+
 def bucket_key(backend: str, metric: str, dtype: str,
                nq: int, n: int, m: int) -> str:
-    """The (backend, metric, dtype, pow-2 shape bucket) table key.
+    """The (backend, metric, dtype, shape bucket) table key.
 
-    Shapes are bucketed to the next power of two — the same bucketing
-    the engine's ragged dispatch uses — so the table stays O(log shape)
-    instead of one entry per distinct size.
+    Batch and query length are bucketed to the next power of two — the
+    same bucketing the engine's ragged dispatch uses — and the reference
+    length by ``m_bucket``, so the table stays O(log shape) instead of
+    one entry per distinct size.
     """
     return (f"{backend}/{metric}/{dtype}/b{_pow2_bucket(max(1, nq))}"
-            f"/n{_pow2_bucket(max(1, n))}/m{_pow2_bucket(max(1, m))}")
+            f"/n{_pow2_bucket(max(1, n))}/m{m_bucket(m, backend)}")
 
 
 class KernelCostModel:
@@ -203,9 +226,11 @@ class KernelCostModel:
                      span: bool = False) -> float:
         """Compiled-kernel time of one (tile, DP row): the larger of the
         row's dependent-chain latency and its vector work (see
-        ``repro.core.platforms.RowChainPrice``)."""
+        ``repro.core.platforms.RowChainPrice``). The scan runs
+        ceil(log2(block_m)) shift steps: 9 for a 384-lane block, as for
+        512."""
         c = self.backend.pallas_price
-        steps = math.log2(block_m)
+        steps = (int(block_m) - 1).bit_length()
         latency = c.lat_fixed_us + c.lat_step_us * steps
         work = c.vreg_step_us * (self.block_vregs(block_q, block_m) * steps
                                  + c.pick_vreg_steps * -(-block_q // 8))
@@ -252,7 +277,8 @@ class KernelCostModel:
         """Ranked ``[((block_q, block_m, scheme, row_tile), us), ...]``.
 
         The candidate set stays deliberately small (it seeds the measured
-        stage): block_m the pow-2 ladder clamped to the reference, the
+        stage): block_m the pow-2 ladder clamped to the reference's
+        ``m_bucket`` (on the TPU {256, 384} for m = 360), the
         scan schemes the backend lowers. Interpret mode: block_q from 1
         up to the batch, no row unrolling. The compiled kernel: block_q
         the sublane multiples of ``TPU_BLOCK_Q_CANDIDATES`` up to the
@@ -269,7 +295,7 @@ class KernelCostModel:
             bq_cands = sorted({bq for bq in (1, 2, 4, 8, 16, 32)
                                if bq <= max(1, nq)} | {min(32, max(1, nq))})
             schemes = ("assoc", "shift")
-        bm_cands = sorted({min(bm, _pow2_bucket(m))
+        bm_cands = sorted({min(bm, m_bucket(m, self.backend.name))
                            for bm in self.BLOCK_M_CANDIDATES})
         scored = []
         for bq in bq_cands:

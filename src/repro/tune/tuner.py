@@ -2,8 +2,9 @@
 
 Stage 1 (``mode='model'``, the default): the analytical
 ``KernelCostModel`` ranks candidate configurations for the request's
-(backend, metric, dtype, pow-2 shape bucket); a shipped/recorded
-``TuningTable`` entry overlays the prediction when one exists.  Stage 2
+(backend, metric, dtype, shape bucket: ``bucket_key``); a
+shipped/recorded ``TuningTable`` entry overlays the prediction when one
+exists.  Stage 2
 (``mode='measure'``): the top model candidates are re-ranked by a short
 on-device measured search — median of ``reps`` timed runs, compile time
 excluded by a warmup call — and the winner is persisted into the
@@ -32,8 +33,8 @@ from typing import Optional
 import numpy as np
 
 from .cache import cached
-from .cost import (TunedConfig, bucket_key, get_cost_model, tuned_n_micro,
-                   _pow2_bucket)
+from .cost import (TunedConfig, bucket_key, get_cost_model, m_bucket,
+                   tuned_n_micro, _pow2_bucket)
 from .table import TuningTable, default_table
 
 #: Measured-search bounds: candidates whose bucket exceeds this many DP
@@ -84,10 +85,11 @@ def resolve(nq: int, n: int, m: int, *, backend: Optional[str] = None,
             mode: str = "model", span: bool = False,
             lastrow: bool = False, rowref: bool = False) -> Resolution:
     """The oracle: LRU -> table -> cost model (-> measured search under
-    ``mode='measure'``).  Costs are evaluated at the bucket's pow-2
-    shape so every shape in a bucket shares one decision; ``span``,
-    ``lastrow`` and ``rowref`` (the kernel variant) size the VMEM
-    working set."""
+    ``mode='measure'``).  Costs are evaluated at the bucket's shape
+    (powers of two; on the TPU a reference up to 4,096 rounded up to
+    whole 128-lane vregs, see ``m_bucket``) so every shape in a bucket
+    shares one decision; ``span``, ``lastrow`` and ``rowref`` (the
+    kernel variant) size the VMEM working set."""
     backend = canonical_backend(backend)
     key = bucket_key(backend, metric, dtype, nq, n, m)
 
@@ -95,7 +97,7 @@ def resolve(nq: int, n: int, m: int, *, backend: Optional[str] = None,
         model = get_cost_model(backend)
         nb = _pow2_bucket(max(1, nq))
         nn = _pow2_bucket(max(1, n))
-        nm = _pow2_bucket(max(1, m))
+        nm = m_bucket(m, backend)
         ranked = tuple(model.rank_impls(nb, nn, nm))
         pal = model.best_pallas(nb, nn, nm, span=span, lastrow=lastrow,
                                 rowref=rowref)
@@ -318,7 +320,7 @@ def record_table(backend: str, shapes=DEFAULT_RECORD_SHAPES, *,
     """Measure every shape bucket and return a fresh ``TuningTable``."""
     table = TuningTable(backend, provenance=provenance)
     for nq, n, m in shapes:
-        nb, nn, nm = (_pow2_bucket(nq), _pow2_bucket(n), _pow2_bucket(m))
+        nb, nn, nm = _pow2_bucket(nq), _pow2_bucket(n), m_bucket(m, backend)
         key = bucket_key(backend, "abs_diff", "int32", nq, n, m)
         model = get_cost_model(backend)
         ranked = model.rank_impls(nb, nn, nm)

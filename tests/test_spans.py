@@ -260,7 +260,10 @@ def test_stream_tick_spans_and_counters(impl, rng):
     for f in fetches:
         assert any(_inside(f, t) for t in ticks)
         assert any(x.end <= f.start for x in launches)
+    # four 16-sample tiles; the kernel runs each as one 16-lane block
+    lanes = 4 * 16 if impl == "pallas" else 0
     assert counters == {"launches": 4, "streams_advanced": 8, "tiles": 4,
-                        "alerts": 1}
+                        "alerts": 1, "lanes_fed": lanes,
+                        "lanes_launched": lanes}
     ev, = s.alerts
     assert (ev.stream, ev.query, ev.distance, ev.end) == (1, 2, 0, 31)

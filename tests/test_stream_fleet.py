@@ -195,3 +195,40 @@ def test_tpu_candidates_fit_vmem_with_the_per_row_tile(span):
         for (bq, bm, _, _), _ in tpu.pallas_candidates(
                 16384, 512, 512, span=span, lastrow=True, rowref=True):
             assert tpu.vmem_words(bq, bm, 512, span, True, True) <= budget
+
+
+def test_fleet_of_360_sample_ticks_at_a_384_lane_block(rng):
+    """The monitoring tick's shape in small: streams fed 360-sample ticks
+    through the kernel at the 384-lane block the TPU tuner picks for
+    them (forced here), each stream equal to its own one-stream session
+    and to the oracle; the lane counters read the 24 padding lanes of
+    each launch."""
+    tick, ticks = 360, 3
+    qs = [rng.integers(-20, 20, n).astype(np.int32) for n in (6, 9)]
+    data = rng.integers(-20, 20, (S, tick * ticks)).astype(np.int32)
+    data[2, 400:409] = qs[1]                   # one exact match
+    kw = dict(chunk=tick, impl="pallas", block_m=384,
+              alert_threshold=THRESHOLD)
+    fleet = stream(qs, streams=S, **kw)
+    for t in range(ticks):
+        fleet.feed(data[:, t * tick:(t + 1) * tick])
+    got = np.asarray(fleet.results().distances)
+    for s in range(S):
+        single = stream(qs, **kw).feed(data[s])
+        np.testing.assert_array_equal(
+            got[s], np.asarray(single.results().distances))
+        mine = [dataclasses.replace(e, stream=0) for e in fleet.alerts
+                if e.stream == s]
+        assert mine == single.alerts
+        for qi, q in enumerate(qs):
+            row = sdtw_last_row(q, data[s])
+            assert got[s, qi] == row.min()
+            alerts = [(e.tile_start, e.hits, e.distance, e.end)
+                      for e in mine if e.query == qi]
+            assert alerts == tile_alerts(row, THRESHOLD, tick)
+    assert any(e.stream == 2 and e.query == 1 and e.distance == 0
+               for e in fleet.alerts)
+    c = fleet.counters()
+    assert (c["launches"], c["lanes_fed"], c["lanes_launched"]) == \
+        (ticks * len(fleet._buckets), ticks * len(fleet._buckets) * tick,
+         ticks * len(fleet._buckets) * 384)

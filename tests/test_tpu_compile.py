@@ -7,7 +7,8 @@ kernel runs on the chip. These tests compile it for a *described* v5e
 topology (``jax.experimental.topologies``) at the paper's Table V query
 lengths, with the TPU default block and with the autotuner's TPU pick
 for a batch of 16 and for the batch sizes of the two batch cells (128
-and 16,384 queries), where the pick is a tall block.
+and 16,384 queries), where the pick is a tall block; and at the
+384-lane block a monitoring tick of 360 samples runs as.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process may load the TPU library at a time, and every test
@@ -202,10 +203,31 @@ def test_fleet_tile_step_compiles_for_v5e(one_chip, topo):
                 spec(rows, n), spec(streams, tick), spec(rows),
                 (spec(rows, n), spec(rows), spec(rows)), spec(), spec(),
                 spec(rows), spec(rows), spec(rows), spec(), impl="pallas",
-                metric="abs_diff", block_q=None, block_m=None, k=1,
+                metric="abs_diff", block_q=None, block_m=None,
+                scan_scheme=None, row_tile=None, k=1,
                 excl_span=False, track=False, want_lastrow=True,
                 with_heap=False, alert=True).compile()
         finally:
             clear_tuning_cache()
     assert bq >= 2 * templates and bm >= tick
+    # a 360-sample tick runs as three whole vregs, not a 512-lane tile
+    assert bm == 384
     assert compiled.as_text().count("tpu_custom_call") >= 1
+
+
+@pytest.mark.parametrize("m", [360, 1000])
+@pytest.mark.parametrize("variant", sorted(VARIANTS) + ["rowref"])
+def test_384_lane_block_compiles_for_v5e(one_chip, variant, m):
+    """A (128, 384) block — a reference tile of three vregs, not a power
+    of two, whose scan still runs 9 shift steps — lowers with Mosaic in
+    every kernel variant, over one tile (a 360-sample tick) and three."""
+    batch, n = 128, 512
+    kw = dict(VARIANTS.get(variant, {}), block_q=batch, block_m=384,
+              scan_scheme="shift", row_tile=2)
+    q = jax.ShapeDtypeStruct((batch, n), jnp.int32, sharding=one_chip)
+    r = jax.ShapeDtypeStruct((batch, m) if variant == "rowref" else (m,),
+                             jnp.int32, sharding=one_chip)
+    fn = jax.jit(lambda q, r: sdtw_pallas(q, r, interpret=False, tune="off",
+                                          **kw))
+    compiled = fn.lower(q, r).compile()
+    assert "tpu_custom_call" in compiled.as_text()

@@ -420,3 +420,90 @@ def test_interpret_price_unchanged(shape, span, top):
         *shape, span=span)[:1]
     assert cfg == top[0]
     assert us == pytest.approx(top[1], rel=1e-12)
+
+
+# ---------------------------------------------------------------------------
+# 5. The TPU reference bucket: whole 128-lane vregs up to the 4096 rung
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def tpu_oracle(monkeypatch):
+    """``resolve(..., backend='tpu')`` on the CPU, with the v5e's cost
+    constants; the autouse fixture empties the LRU around it."""
+    from repro.core import platforms
+    from repro.tune import cost
+    monkeypatch.setattr(platforms, "tpu_device_kind", lambda: "TPU v5 lite")
+    monkeypatch.setattr(cost, "_MODELS", {})
+
+
+@pytest.mark.parametrize("m,tpu,interpret", [
+    (100, 128, 128), (256, 256, 256), (360, 384, 512), (500, 512, 512),
+    (700, 768, 1024), (4096, 4096, 4096), (4097, 8192, 8192),
+    (7997, 8192, 8192), (262144, 262144, 262144)])
+def test_reference_bucket(m, tpu, interpret):
+    from repro.tune.cost import m_bucket
+    assert m_bucket(m, "tpu") == tpu
+    assert m_bucket(m, "interpret") == interpret
+
+
+def test_tpu_tick_ranks_a_384_lane_block_first(tpu_oracle):
+    """The monitoring tick's call — 1,024 streams x 16 templates of 512
+    against a 360-sample tile, last row captured, per-row reference — is
+    priced at 384 lanes, and a 384-wide block ranks first."""
+    cands = _tpu_model().pallas_candidates(16384, 512, 384, lastrow=True,
+                                           rowref=True)
+    assert {bm for (_, bm, _, _), _ in cands} == {256, 384}
+    assert cands[0][0][1] == 384
+    cfg = resolve(16384, 512, 360, backend="tpu", lastrow=True,
+                  rowref=True).config
+    assert (cfg.block_q, cfg.block_m, cfg.scan_scheme, cfg.row_tile) == \
+        (128, 384, "shift", 2)
+
+
+@pytest.mark.parametrize("shape,kw,pick", [
+    ((128, 512, 262144), dict(span=True), (128, 512, "shift", 2)),
+    ((16384, 120, 7997), {}, (128, 512, "shift", 2)),
+    ((1, 120, 7997), {}, (8, 4096, "shift", 2)),
+])
+def test_tpu_cell_picks_unchanged(tpu_oracle, shape, kw, pick):
+    """ecg-offline-spans, human-batch and the served cells: references
+    past the 4096 rung keep their power-of-two bucket and the blocks the
+    row-chain price picked for them."""
+    assert tuned_blocks(shape[0], shape[2], n=shape[1], backend="tpu",
+                        **kw) == pick
+
+
+def test_tpu_picks_for_360_and_500_are_not_shared(tpu_oracle):
+    a = resolve(16384, 512, 360, backend="tpu", lastrow=True, rowref=True)
+    b = resolve(16384, 512, 500, backend="tpu", lastrow=True, rowref=True)
+    assert (a.config.block_m, b.config.block_m) == (384, 512)
+    keys = {k[0] for k in cache_keys()}
+    assert {bucket_key("tpu", "abs_diff", "int32", 16384, 512, 360),
+            bucket_key("tpu", "abs_diff", "int32", 16384, 512, 500)} <= keys
+    assert len(keys) == 2
+    # interpret mode keeps one power-of-two bucket for both
+    assert bucket_key("interpret", "abs_diff", "int32", 1, 8, 360) == \
+        bucket_key("interpret", "abs_diff", "int32", 1, 8, 500)
+
+
+@pytest.mark.parametrize("span", [False, True])
+@pytest.mark.parametrize("block_q", [8, 128])
+def test_row_chain_prices_the_ceiling_of_the_scan_steps(block_q, span):
+    """Power-of-two blocks keep the price they had (log2 steps); a
+    384-lane block pays 9 shift steps, as its scan runs."""
+    import math
+    model = _tpu_model()
+    c = model.backend.pallas_price
+
+    def price(bm, steps):
+        lat = c.lat_fixed_us + c.lat_step_us * steps
+        work = c.vreg_step_us * (model.block_vregs(block_q, bm) * steps
+                                 + c.pick_vreg_steps * -(-block_q // 8))
+        if span:
+            lat, work = lat * c.span_lat_mult, work * c.span_work_mult
+        return max(lat, work)
+
+    for bm in (128, 256, 512, 1024, 2048, 4096):
+        assert model.row_chain_us(block_q, bm, span) == \
+            price(bm, math.log2(bm))
+    assert model.row_chain_us(block_q, 384, span) == price(384, 9)
